@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import engine
-from repro.launch.mesh import HW
+from repro.launch.mesh import V5E, peaks
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
 
@@ -50,10 +50,11 @@ def stage_model(b: int, n: int, m: int, plastic: bool = True) -> dict:
         "fused_bytes": fused_bytes,
         "sequential_bytes": seq_bytes,
     }
+    hw = peaks(V5E)
     for stage in ("forward", "plasticity"):
         s = out[stage]
-        s["compute_us"] = s["flops"] / HW["peak_flops_bf16"] * 1e6
-        s["memory_us"] = s["bytes"] / HW["hbm_bw"] * 1e6
+        s["compute_us"] = s["flops"] / hw["peak_flops_bf16"] * 1e6
+        s["memory_us"] = s["bytes"] / hw["hbm_bw"] * 1e6
         s["roofline_us"] = max(s["compute_us"], s["memory_us"])
     return out
 

@@ -15,13 +15,12 @@ TPU program on CPU; on TPU pass ``--impl pallas``.
 
 Baseline honesty notes:
 
-  * On the Pallas backends the vmap baseline MUST materialize theta per
-    stream (``in_axes theta=0``): jax 0.4.37's pallas_call batching rule
-    cannot carry an unmapped operand — ``in_axes=None`` fails to lower
-    ("ValueError: Block shape for refs[...] must have the same number of
-    dimensions as the array shape (B, 4, N, M)"), i.e. the historical
-    recipe was never runnable on pallas/pallas-interpret at all, and the
-    broadcast is what its batching rule attempts internally anyway.
+  * On the Pallas backends the vmap baseline materializes theta per
+    stream (``in_axes theta=0``): the pallas_call batching rule cannot
+    carry an unmapped operand — ``in_axes=None`` fails to lower, i.e. the
+    historical recipe was never runnable on pallas/pallas-interpret at
+    all, and the broadcast is what its batching rule attempts internally
+    anyway.
   * On ``--impl xla`` the two paths are the SAME lowering by construction
     (the fleet oracle in kernels/plasticity/ref.py is defined as the vmap
     of the unbatched step), so expect parity there — the kernel-launch and
@@ -35,16 +34,18 @@ interpret backend; fusing K steps and blocking ``block_b`` streams per
 grid program divides that overhead by K * block_b.
 
 The DEVICE sweep (``--device-counts``, on by default) measures the sharded
-session pool: for each D in the sweep a fresh subprocess forces D host
-devices (``XLA_FLAGS=--xla_force_host_platform_device_count=D`` must be set
-before jax initializes, hence the subprocess), runs a meshed
-`serving.scheduler.FleetScheduler` (`distributed.sharding.fleet_mesh`), and
-reports the fused pool-step rate, scaling efficiency vs D=1, and the
-device-loss drain latency (`fail_device` -> `drain_failed`; D=1 has no
-surviving shard, so its drain cells are null).  Zero recompiles across the
-timed section AND the drain are asserted in every cell.  On forced host
-devices all D shards share one physical CPU, so efficiency ~1/D is
-expected — the sweep pins the mechanism and the drain path, not a speedup.
+session pool: for each D in the sweep it runs, in this process, a meshed
+`serving.scheduler.FleetScheduler` on the first D visible devices
+(`distributed.sharding.fleet_mesh`), and reports the fused pool-step rate,
+scaling efficiency vs D=1, and the device-loss drain latency
+(`fail_device` -> `drain_failed`; D=1 has no surviving shard, so its drain
+cells are null).  Zero recompiles across the timed section AND the drain
+are asserted in every cell.  The counts default to every power of two up
+to the visible device count; one process drives every device, so on a TPU
+host the sweep runs over the real chips.  CPU CI gets several devices from
+the job's environment (``XLA_FLAGS=--xla_force_host_platform_device_count``);
+there all D shards share one physical CPU, so efficiency ~1/D is expected —
+the sweep pins the mechanism and the drain path, not a speedup.
 
     PYTHONPATH=src python benchmarks/fleet_throughput.py [--smoke] [--impl ...]
 
@@ -72,8 +73,6 @@ import dataclasses
 import functools
 import json
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -82,10 +81,6 @@ import jax.numpy as jnp
 from repro.core import engine
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
-
-# child -> parent protocol for the device sweep: the cell subprocess prints
-# exactly one line with this prefix followed by the cell JSON
-_CELL_MARK = "DEVICE_CELL_JSON:"
 
 
 def make_fleet(b: int, n: int, m: int, key: jax.Array):
@@ -161,22 +156,16 @@ def bench_fused_steps_per_s(layer, x, params, impl: str, k: int,
 # ---- the sharded-pool device sweep -----------------------------------------
 
 
-def _device_cell(args) -> int:
-    """One device-sweep cell, run in a subprocess with D forced devices:
-    meshed pool-step throughput + device-loss drain latency, with zero
-    recompiles asserted across both."""
+def _device_cell(args, d: int) -> dict:
+    """One device-sweep cell on the first `d` visible devices: meshed
+    pool-step throughput + device-loss drain latency, with zero recompiles
+    asserted across both."""
     import numpy as np
 
     from repro.core import snn
     from repro.distributed import sharding as dsh
     from repro.serving.scheduler import FleetScheduler
 
-    d = int(args.devices)
-    if len(jax.devices()) < d:
-        raise RuntimeError(
-            f"device cell needs {d} devices but jax sees "
-            f"{len(jax.devices())} — the parent must set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={d} before spawn")
     slots = args.slots if args.slots else (8 if args.smoke else 16)
     cfg = snn.SNNConfig(layer_sizes=(args.n, args.m), impl=args.impl,
                         block_m=args.block_m)
@@ -238,36 +227,19 @@ def _device_cell(args) -> int:
 
     cell["recompiles"] = sched.compile_count() - warm
     assert cell["recompiles"] == 0, sched.compiled_programs()
-    print(_CELL_MARK + json.dumps(cell))
-    return 0
+    return cell
 
 
 def _run_device_sweep(args):
-    """Spawn one `--device-cell` subprocess per device count (the forced-
-    host-device flag is per-process and pre-import) and aggregate scaling
+    """Run one in-process cell per device count and aggregate scaling
     efficiency vs the first count (1 by default)."""
-    counts = [int(c) for c in str(args.device_counts).split(",") if c]
-    cells = []
+    if args.device_counts:
+        counts = [int(c) for c in str(args.device_counts).split(",") if c]
+    else:
+        n = len(jax.devices())
+        counts = [1 << i for i in range(n.bit_length()) if 1 << i <= n]
     print("devices,pool_steps_per_s,scaling_efficiency,drain_ms,recompiles")
-    for d in counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
-        cmd = [sys.executable, os.path.abspath(__file__), "--device-cell",
-               "--devices", str(d), "--impl", args.impl,
-               "--n", str(args.n), "--m", str(args.m),
-               "--block-m", str(args.block_m), "--k", str(args.k)]
-        if args.slots:
-            cmd += ["--slots", str(args.slots)]
-        if args.smoke:
-            cmd.append("--smoke")
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=1800, env=env)
-        if proc.returncode != 0:
-            raise RuntimeError(f"device cell D={d} failed:\n"
-                               f"{proc.stdout}\n{proc.stderr}")
-        lines = [ln for ln in proc.stdout.splitlines()
-                 if ln.startswith(_CELL_MARK)]
-        cells.append(json.loads(lines[-1][len(_CELL_MARK):]))
+    cells = [_device_cell(args, d) for d in counts]
     base = cells[0]["pool_steps_per_s"]
     for c in cells:
         c["speedup_vs_1dev"] = c["pool_steps_per_s"] / base
@@ -300,25 +272,19 @@ def main(argv=None):
                          "fleet_throughput.json, or a separate _smoke file "
                          "under --smoke so CI/quick runs never clobber the "
                          "checked-in full-sweep artifact")
-    ap.add_argument("--device-counts", default="1,2,4,8",
+    ap.add_argument("--device-counts", default=None,
                     help="comma-separated device counts for the sharded-"
-                         "pool sweep (each runs in a subprocess with that "
-                         "many forced host devices); the first count is "
-                         "the scaling-efficiency baseline")
+                         "pool sweep, each at most the visible device count "
+                         "(default: every power of two up to it); the first "
+                         "count is the scaling-efficiency baseline")
     ap.add_argument("--devices-only", action="store_true",
                     help="run ONLY the device sweep and merge it into the "
                          "--out artifact, preserving an existing B sweep "
                          "(CI regenerates device cells without re-running "
                          "the minutes-long B=1024 rows)")
-    ap.add_argument("--device-cell", action="store_true",
-                    help=argparse.SUPPRESS)  # internal: subprocess mode
-    ap.add_argument("--devices", type=int, default=None,
-                    help=argparse.SUPPRESS)  # internal: cell device count
     ap.add_argument("--slots", type=int, default=None,
                     help="device-sweep pool size (default 8 smoke / 16)")
     args = ap.parse_args(argv)
-    if args.device_cell:
-        return _device_cell(args)
     if args.out is None:
         capped = args.max_batch is not None and args.max_batch < 1024
         name = ("fleet_throughput_smoke.json" if args.smoke else

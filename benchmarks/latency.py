@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import snn
-from repro.launch.mesh import HW
+from repro.launch.mesh import V5E, peaks
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
 
@@ -35,11 +35,13 @@ def controller_roofline_us(obs: int, hidden: int, act: int,
                            timesteps: int) -> float:
     """Roofline latency of one control step on one v5e core."""
     d = 2
+    hw = peaks(V5E)
     total = 0.0
     for (n, m) in ((obs, hidden), (hidden, act)):
         flops = 2 * n * m + 2 * n * m + 10 * m          # fwd + hebb + pointwise
         byts = d * (5 * n * m + 2 * n + 4 * m)          # w + theta(4) + traces
-        total += max(flops / HW["peak_flops_bf16"], byts / HW["hbm_bw"]) * 1e6
+        total += max(flops / hw["peak_flops_bf16"],
+                     byts / hw["hbm_bw"]) * 1e6
     return total * timesteps
 
 

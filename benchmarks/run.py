@@ -183,6 +183,8 @@ def main(argv=None):
     quick = "--quick" in argv or "--smoke" in argv
     check = "--check" in argv
     reference = _reference_results() if check else {}
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     failures = []
 

@@ -28,8 +28,9 @@ batched semantics, selected by the weight rank:
   * FLEET mode, ``w (B, N, M)`` — every request stream owns and rewrites
     its OWN synapses with a per-sample dw under one shared rule theta.
     All three backends run the whole fleet as ONE fused program (the Pallas
-    kernel launches a ``(cdiv(M, bm), B)`` grid, streams innermost so the
-    shared theta tile is fetched once per tile); this replaces the old
+    kernel launches a ``(cdiv(M, bm), cdiv(B, block_b))`` grid, stream
+    blocks innermost so the shared theta tile is fetched once per tile);
+    this replaces the old
     recipe of `jax.vmap`-ing `layer_step` per stream, which broadcast the
     shared rule theta B-fold and never lowered through `pallas_call` at
     all (the batching rule rejects unmapped operands).
@@ -655,8 +656,8 @@ def fleet_spmd(fn, mesh, in_axes, out_axes, axis_name: str = "data"):
 
     `shard_map` rather than sharded jit because GSPMD has no partitioning
     rule for `pallas_call` — manual SPMD is what lets the megakernel run
-    per-shard unchanged.  ``check_rep=False`` for the same reason (Pallas
-    calls carry no replication rule).
+    per-shard unchanged.  ``check_vma=False`` for the same reason (Pallas
+    calls carry no varying-manual-axes rule).
 
     Args:
       fn:       positional-argument function over fleet pytrees.
@@ -667,11 +668,10 @@ def fleet_spmd(fn, mesh, in_axes, out_axes, axis_name: str = "data"):
                 state, 1 for time-major ``(K, B, ...)`` windows) — or None
                 for replicated inputs (scalars, shared rule state).
       out_axes: same, per output; every output must be slot-mapped (an
-                int): with ``check_rep=False`` a replicated output cannot
+                int): with ``check_vma=False`` a replicated output cannot
                 be verified, so compute pool-global outputs OUTSIDE the
                 wrapped call.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     def spec(ax, kind):
@@ -683,8 +683,8 @@ def fleet_spmd(fn, mesh, in_axes, out_axes, axis_name: str = "data"):
             return PartitionSpec()
         return PartitionSpec(*((None,) * ax), axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=tuple(spec(a, "in") for a in in_axes),
         out_specs=tuple(spec(a, "out") for a in out_axes),
-        check_rep=False)
+        check_vma=False)

@@ -27,10 +27,11 @@ Modes — the same body serves all four datapaths:
     grid (1,), the whole batch in one program.
   * FLEET (w (B, N, M), per-sample dw, shared theta, optional `active`
     slot mask): grid (cdiv(B, block_b),) — `block_b` request streams per
-    program, the stream axis carried *inside* the block (one einsum
-    forward, broadcast outer-product Hebbian), which divides the dominant
-    per-grid-iteration overhead of interpret mode by block_b while staying
-    bit-identical to per-stream execution (streams never interact).
+    program, the stream axis carried *inside* the block (one batched
+    matmul forward, broadcast outer-product Hebbian); on TPU ``block_b``
+    is a multiple of 8 or the whole fleet (the sublane tiling rule).  It
+    divides the per-grid-iteration overhead by block_b while computing each
+    stream independently (streams never interact).
   * float32 and the PR-4 fixed-point datapath (int8 weights promoted to
     int32 registers for the window, int32 membranes/traces, deterministic
     stochastic rounding seeded per session and per STEP: step k of the
@@ -73,6 +74,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.plasticity import ALPHA, BETA, GAMMA, DELTA
 from repro.kernels.plasticity import quant as Q
+from repro.kernels.plasticity import kernel as _K
 from repro.obs.telemetry import sat_threshold, sat_threshold_q
 
 
@@ -147,11 +149,7 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
         for i in range(n_layers):
             w, v, tpost = ws[i], vs[i], trs[i + 1]
             # ---- Forward Engine: psum on the resident weight tile --------
-            if fleet:
-                acc = jnp.einsum("bn,bnm->bm", x, w,
-                                 preferred_element_type=compute)
-            else:
-                acc = jnp.dot(x, w, preferred_element_type=compute)
+            acc = _K.psum(x, w, fleet=fleet, quant=qcfg is not None)
             current = acc if qcfg is None else Q.current_fx(acc, scales[i],
                                                             qcfg)
             if teach_ref is not None and i == n_layers - 1:
@@ -191,9 +189,8 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
                               + th[GAMMA] * tpost_p[:, None, :]
                               + th[DELTA])
                     else:       # shared weights: batch-averaged dw
-                        hebb = jnp.dot(
-                            tpre.T, tpost_p,
-                            preferred_element_type=jnp.float32) / batch
+                        hebb = _K.shared_hebb(tpre, tpost_p,
+                                           quant=False) / batch
                         pre_m = jnp.mean(tpre, axis=0)
                         post_m = jnp.mean(tpost_p, axis=0)
                         dw = (th[ALPHA] * hebb + th[BETA] * pre_m[:, None]
@@ -207,8 +204,7 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
                         scale = scales[i][:, :, None]             # (bb,1,1)
                         seed_i = Q.fold_seed(base_seed + k, i)[:, :, None]
                     else:
-                        hebb_i = jnp.dot(tpre.T, tpost_p,
-                                         preferred_element_type=jnp.int32)
+                        hebb_i = _K.shared_hebb(tpre, tpost_p, quant=True)
                         dw = Q.dw_from_int_reductions(
                             hebb_i, tpre.sum(0), tpost_p.sum(0), th,
                             batch, qcfg)
@@ -238,10 +234,9 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
                     sat = jnp.abs(v_upd) >= sat_threshold_q(v_th, qcfg)
                 else:
                     sat = jnp.abs(v_upd) >= sat_threshold(v_th)
-                tel = tel + jnp.stack(
-                    [jnp.sum(ev_f, axis=1) / m_i,
-                     jnp.sum(sat.astype(jnp.float32), axis=1) / m_i],
-                    axis=1)
+                tel = (tel[0] + jnp.sum(ev_f, axis=1, keepdims=True) / m_i,
+                       tel[1] + jnp.sum(sat.astype(jnp.float32), axis=1,
+                                        keepdims=True) / m_i)
             out = events if spiking[i] else v_upd
             if gate is not None and not spiking[i]:
                 # readout output IS the membrane; inactive slots must still
@@ -253,8 +248,9 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
         return new + ((tel,) if telemetry else ())
 
     carry = (ws0, vs0, trs0)
-    if telemetry:
-        carry = carry + (jnp.zeros((ws0[0].shape[0], 2), jnp.float32),)
+    if telemetry:   # (bb, 1) columns: Mosaic has no 1-D vector layouts
+        zero = jnp.zeros((ws0[0].shape[0], 1), jnp.float32)
+        carry = carry + ((zero, zero),)
     if unroll_k <= 0 or unroll_k >= k_steps:
         for k in range(k_steps):                      # full unroll
             carry = one_step(k, carry)
@@ -278,24 +274,24 @@ def _rollout_kernel(*refs, n_layers, k_steps, spiking, plastic, fleet,
         tr_outs[i][...] = trs[i].astype(tr_outs[i].dtype)
 
     if telemetry:
-        tel_acc = carry[3]
+        tel_spk, tel_sat = carry[3]
         kl = float(k_steps * n_layers)
-        spike_rate = tel_acc[:, 0] / kl
-        sat_frac = tel_acc[:, 1] / kl
+        spike_rate = tel_spk / kl                     # (bb, 1)
+        sat_frac = tel_sat / kl
         plast = [i for i in range(n_layers) if plastic[i]]
         if plast:
             dw_sum = jnp.zeros_like(spike_rate)
             for i in plast:
                 n_i, m_i = ws[i].shape[-2], ws[i].shape[-1]
                 d = jnp.abs(ws[i] - ws0[i]).astype(jnp.float32)
-                per_slot = jnp.sum(d, axis=(1, 2))
+                per_slot = jnp.sum(jnp.sum(d, axis=2), axis=1, keepdims=True)
                 if qcfg is not None:
-                    per_slot = per_slot * scales[i][:, 0]
+                    per_slot = per_slot * scales[i]
                 dw_sum = dw_sum + per_slot / (n_i * m_i)
             mean_dw = dw_sum / float(k_steps * len(plast))
         else:
             mean_dw = jnp.zeros_like(spike_rate)
-        row = jnp.stack([spike_rate, mean_dw, sat_frac], axis=1)  # (bb, 3)
+        row = _K.columns3(spike_rate, mean_dw, sat_frac)          # (bb, 3)
         if gate is not None:
             row = row * gate.astype(jnp.float32)      # (bb, 1) broadcast
         tel_out[...] = row
@@ -357,7 +353,7 @@ def rollout_pallas(drives, ws, thetas, vs, traces, *, spiking, plastic,
                          "(per-slot rows need a leading stream rank)")
 
     if fleet:
-        bb = min(block_b, b)
+        bb = _K.stream_block(block_b, b, interpret)
         grid = (pl.cdiv(b, bb),)
         tmap = lambda i: (0, i, 0)      # time-major staging (K, bb, n)
         wmap = lambda i: (i, 0, 0)      # per-stream weight block

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.plasticity import ALPHA, BETA, GAMMA, DELTA
@@ -169,6 +170,42 @@ def dw_from_int_reductions(hebb_i32, pre_sum_i32, post_sum_i32, theta,
             + th[GAMMA] * post_m[..., None, :] + th[DELTA])
 
 
+def _int8_limbs(a):
+    """int32 -> five int8 limbs with ``a == sum_k limb_k * 2**(7k)``:
+    four unsigned 7-bit limbs and a signed top limb ``a >> 28``."""
+    limbs = [((a >> (7 * k)) & 127).astype(jnp.int8) for k in range(4)]
+    return limbs + [(a >> 28).astype(jnp.int8)]
+
+
+def int_matmul(a, b, contract_lhs: int = 1):
+    """Exact int32 matrix product from int8 x int8 -> int32 MXU matmuls.
+
+    Contracts ``a``'s dim `contract_lhs` (1: ``a @ b``; 0: ``a.T @ b``)
+    with ``b``'s dim 0.  An int8 operand is used as is; an int32 one is
+    split into 7-bit int8 limbs (`_int8_limbs`), and the limb products are
+    shifted back into place.  Every limb product is exact (|sum| <=
+    K * 127**2 < 2**31 for K < 133k), and the shifted sum equals the int32
+    product modulo 2**32 — the same two's-complement result as an int32
+    ``jnp.dot`` — so the result is bitwise the oracle's.  Needed because
+    the TPU MXU takes no int32 operands.
+    """
+    dims = (((contract_lhs,), (0,)), ((), ()))
+    la = [a] if a.dtype == jnp.int8 else _int8_limbs(a)
+    lb = [b] if b.dtype == jnp.int8 else _int8_limbs(b)
+    acc = None
+    for i, x in enumerate(la):
+        for j, y in enumerate(lb):
+            shift = 7 * (i + j)
+            if shift >= 32:              # vanishes modulo 2**32
+                continue
+            term = jax.lax.dot_general(x, y, dims,
+                                       preferred_element_type=jnp.int32)
+            if shift:
+                term = jnp.left_shift(term, jnp.int32(shift))
+            acc = term if acc is None else acc + term
+    return acc
+
+
 # ---- deterministic stochastic rounding -------------------------------------
 
 def uniform_hash(seed, idx):
@@ -187,7 +224,10 @@ def uniform_hash(seed, idx):
     h = h ^ (h >> jnp.uint32(13))
     h = h * jnp.uint32(0x27D4EB2F)
     h = h ^ (h >> jnp.uint32(16))
-    return (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    # via int32: the 24-bit value is exact in both, and TPU kernels have no
+    # uint32 -> float32 conversion
+    return ((h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(2.0 ** -24))
 
 
 def round_steps(steps_f32, seed, idx, qc: QuantConfig):

@@ -83,9 +83,12 @@ def dual_engine_step(x, w, theta, v, trace_pre, trace_post, *,
     teach (B,M)|(M,)|None.
 
     Returns (events, v_out, trace_post_new, w_new) with batch rank preserved.
+    Contractions run at ``Precision.HIGHEST``: float32 semantics on every
+    backend (TPU's default for a float32 dot is one bfloat16 pass).
     """
     compute = jnp.float32
-    current = jnp.dot(x.astype(compute), w.astype(compute))
+    current = jnp.dot(x.astype(compute), w.astype(compute),
+                      precision=jax.lax.Precision.HIGHEST)
     if teach is not None:
         current = current + teach.astype(compute)
     v32 = v.astype(compute)
@@ -105,7 +108,8 @@ def dual_engine_step(x, w, theta, v, trace_pre, trace_post, *,
             tpre, tpo = tpre[None], tpo[None]
         b = tpre.shape[0]
         th = theta.astype(compute)
-        hebb = jnp.einsum("bi,bj->ij", tpre, tpo) / b
+        hebb = jnp.einsum("bi,bj->ij", tpre, tpo,
+                          precision=jax.lax.Precision.HIGHEST) / b
         pre_m = tpre.mean(0)
         post_m = tpo.mean(0)
         dw = (th[ALPHA] * hebb + th[BETA] * pre_m[:, None]

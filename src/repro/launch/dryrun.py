@@ -48,7 +48,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     from repro.distributed import sharding as shd
     from repro.launch import hlo_analysis, steps
-    from repro.launch.mesh import HW, make_production_mesh
+    from repro.launch.mesh import V5E, make_production_mesh, peaks
     from repro.launch.specs import input_specs
     from repro.optim import adamw, warmup_cosine
 
@@ -119,8 +119,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         mem_rec["live_bytes_per_device"] = live
         est = estimate_tpu_memory(spec, mesh)
         mem_rec.update(est)
-        mem_rec["hbm_frac"] = est["tpu_live_bytes"] / HW["hbm_bytes"]
-        mem_rec["hbm_frac_cpu_compiled"] = live / HW["hbm_bytes"]
+        hw = peaks(V5E)
+        mem_rec["hbm_frac"] = est["tpu_live_bytes"] / hw["hbm_bytes"]
+        mem_rec["hbm_frac_cpu_compiled"] = live / hw["hbm_bytes"]
         print(f"[{mesh_kind}] {arch} x {shape_name}: "
               f"tpu-est {est['tpu_live_bytes']/2**30:.2f} GiB/chip "
               f"({100*mem_rec['hbm_frac']:.0f}% of HBM); "
@@ -143,7 +144,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "n_params": _n_params(cfg),
             "n_active_params": steps.n_active_params(cfg),
         })
-        terms = hlo_analysis.roofline_terms(hlo, HW)
+        terms = hlo_analysis.roofline_terms(hlo, hw)
         out["roofline"] = terms
         print({k: (f"{v:.3e}" if isinstance(v, float) else v)
                for k, v in terms.items()})

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke
 from repro.distributed import sharding as shd
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import make_decode_step, make_prefill
 from repro.models import factory
@@ -208,6 +209,7 @@ def main(argv=None):
     if args.flight_dir and not args.plastic:
         ap.error("--flight-dir records the plastic adapter's health "
                  "channels; pass --plastic too")
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.plastic:

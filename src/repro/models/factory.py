@@ -125,12 +125,15 @@ class Model:
     def prefill(self, params, inputs, max_len: int, **kw):
         return T.prefill(params, inputs, self.cfg, max_len, **kw)
 
-    def decode_step(self, params, cache, tokens, active=None):
-        return T.decode_step(params, cache, tokens, self.cfg, active=active)
+    def decode_step(self, params, cache, tokens, active=None,
+                    slot_mesh=None):
+        return T.decode_step(params, cache, tokens, self.cfg, active=active,
+                             slot_mesh=slot_mesh)
 
-    def decode_rollout(self, params, cache, tokens, active=None):
+    def decode_rollout(self, params, cache, tokens, active=None,
+                       slot_mesh=None):
         return T.decode_rollout(params, cache, tokens, self.cfg,
-                                active=active)
+                                active=active, slot_mesh=slot_mesh)
 
     def cache_plan(self, batch: int, max_len: int,
                    per_slot_index: bool = False):

@@ -12,6 +12,7 @@ keeping init / abstract / sharding structurally identical by construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -39,6 +40,13 @@ def _is_desc(x):
     return isinstance(x, ParamDesc)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _scaled_normal(key, shape, std, dtype):
+    # one program per (shape, std, dtype): the float32 draw is fused into
+    # the cast, so a multi-GiB stacked-layer leaf never exists in float32
+    return (std * jax.random.normal(key, shape)).astype(dtype)
+
+
 def init_from_plan(plan, key: jax.Array):
     leaves, treedef = jax.tree.flatten(plan, is_leaf=_is_desc)
     keys = jax.random.split(key, len(leaves))
@@ -53,7 +61,7 @@ def init_from_plan(plan, key: jax.Array):
             return jnp.full(desc.shape, desc.scale, dt)
         fan = desc.fan_in if desc.fan_in else (desc.shape[-2] if len(desc.shape) >= 2 else desc.shape[-1])
         std = desc.scale / (fan ** 0.5)
-        return (std * jax.random.normal(k, desc.shape)).astype(dt)
+        return _scaled_normal(k, tuple(desc.shape), std, dt)
 
     return jax.tree.unflatten(treedef, [mk(d, k) for d, k in zip(leaves, keys)])
 

@@ -12,7 +12,7 @@ Phase 2).  Per decode step, per request:
 The synaptic layer between the two populations is ONE fleet-mode
 `core.engine.layer_step` over the whole batch: W_fast carries a leading
 request rank (B, N, N) and every decode stream rewrites its own synapses
-with a per-sample dw inside a single fused launch (grid (tiles, B) on
+with a per-sample dw inside a single fused launch (grid (tiles, B/8) on
 Pallas) — not B vmap-stamped kernel calls.  The serving hot path runs the
 SAME fused dual-engine program as the SNN controller; ``cfg.adapter_impl``
 selects the backend ("xla" | "pallas" | "pallas-interpret").
@@ -121,13 +121,26 @@ def _gate(active, new, old):
     return jnp.where(mask, new, old)
 
 
+def _per_shard(fn, slot_mesh, in_axes, out_axes):
+    """Run `fn` per slot shard of `slot_mesh` (identity without a mesh).
+
+    The synaptic layer's slot rows are independent, so a sharded pool runs
+    the same engine program on each device's rows (`engine.fleet_spmd`);
+    GSPMD cannot partition the Pallas kernel itself."""
+    if slot_mesh is None:
+        return fn
+    return engine.fleet_spmd(fn, slot_mesh, in_axes, out_axes)
+
+
 def decode_step(params, state: dict, h, cfg: ModelConfig,
                 trace_decay: float = 0.8, w_clip: float = 4.0,
-                active=None):
+                active=None, slot_mesh=None):
     """h (B,1,D) -> (h', new_state).  One online plasticity step per token.
 
     ``active (B,)`` (optional) freezes vacant pool slots bit-exactly —
-    presynaptic state, synaptic layer, and step counter alike."""
+    presynaptic state, synaptic layer, and step counter alike.
+    ``slot_mesh`` (optional): the mesh whose ``"data"`` axis shards the
+    slot rows; the engine launch then runs per shard."""
     quant = cfg.adapter_quant
     drive = jnp.einsum("bd,dn->bn", h[:, 0].astype(jnp.float32),
                        params["p_in"].astype(jnp.float32))
@@ -145,21 +158,29 @@ def decode_step(params, state: dict, h, cfg: ModelConfig,
     # all request streams — w_fast (B, N, N) triggers per-sample dw, each
     # stream rewriting its own W_fast against the shared rule theta.
     ep = _engine_params(cfg, trace_decay, w_clip)
-    layer = engine.LayerState(
-        w=state["w_fast"], v=state["v2"], trace_pre=tr1,
-        trace_post=state["tr2"], theta=params["theta"].astype(jnp.float32),
-        w_scale=state.get("w_scale"))
-    layer, s2 = engine.layer_step(
-        layer, x, params=ep, impl=cfg.adapter_impl, active=active,
-        seed=Q.fold_seed(state["t"], 0) if quant else None)
+
+    def synapse(w, v, tpre, tpost, x, active, seed, w_scale, theta):
+        layer = engine.LayerState(w=w, v=v, trace_pre=tpre,
+                                  trace_post=tpost, theta=theta,
+                                  w_scale=w_scale)
+        layer, s2 = engine.layer_step(layer, x, params=ep,
+                                      impl=cfg.adapter_impl, active=active,
+                                      seed=seed)
+        return layer.w, layer.v, layer.trace_post, s2
+
+    synapse = _per_shard(synapse, slot_mesh, (0,) * 8 + (None,), (0,) * 4)
+    w_fast, v2, tr2, s2 = synapse(
+        state["w_fast"], state["v2"], tr1, state["tr2"], x, active,
+        Q.fold_seed(state["t"], 0) if quant else None, state.get("w_scale"),
+        params["theta"].astype(jnp.float32))
 
     s2f = Q.from_fixed(s2, QUANT) if quant else s2
     out = jnp.einsum("bn,nd->bd", s2f, params["p_out"].astype(jnp.float32))
     if active is not None:
         out = out * active.astype(jnp.float32)[:, None]
     h = h + (params["scale"] * out[:, None, :]).astype(h.dtype)
-    new_state = {"w_fast": layer.w, "v1": v1, "v2": layer.v,
-                 "tr1": tr1, "tr2": layer.trace_post,
+    new_state = {"w_fast": w_fast, "v1": v1, "v2": v2,
+                 "tr1": tr1, "tr2": tr2,
                  "t": state["t"] + _gate(active, jnp.ones((), jnp.int32),
                                          jnp.zeros((), jnp.int32))}
     if quant:
@@ -169,7 +190,7 @@ def decode_step(params, state: dict, h, cfg: ModelConfig,
 
 def decode_rollout(params, state: dict, h, cfg: ModelConfig,
                    trace_decay: float = 0.8, w_clip: float = 4.0,
-                   active=None):
+                   active=None, slot_mesh=None):
     """h (B, K, D) -> (h', new_state).  K plasticity steps, ONE fused launch.
 
     The multi-token form of K sequential `decode_step` calls — speculative
@@ -186,6 +207,7 @@ def decode_rollout(params, state: dict, h, cfg: ModelConfig,
     oracle is the same `layer_step` program.  In quant mode step k of the
     window draws its stochastic round from the per-session counter
     ``t + k`` — exactly the sequence K single `decode_step` calls would.
+    ``slot_mesh`` as in `decode_step`.
     """
     quant = cfg.adapter_quant
     p_in = params["p_in"].astype(jnp.float32)
@@ -200,15 +222,23 @@ def decode_rollout(params, state: dict, h, cfg: ModelConfig,
     v1, s1_series = jax.lax.scan(pre, state["v1"], hk)   # (K, B, N)
 
     ep = _engine_params(cfg, trace_decay, w_clip)
-    net = engine.NetworkState(
-        w=(state["w_fast"],), v=(state["v2"],),
-        trace=(state["tr1"], state["tr2"]), t=jnp.zeros((), jnp.int32),
-        w_scale=(state["w_scale"],) if quant else ())
+
+    def synapses(w, v, tr1, tr2, drives, active, seed, w_scale, theta):
+        net = engine.NetworkState(
+            w=(w,), v=(v,), trace=(tr1, tr2), t=jnp.zeros((), jnp.int32),
+            w_scale=(w_scale,) if quant else ())
+        net, s2_series = engine.rollout(
+            net, [theta], drives, params=ep, impl=cfg.adapter_impl,
+            active=active, seed=seed)
+        return net.w[0], net.v[0], net.trace[0], net.trace[1], s2_series
+
+    synapses = _per_shard(synapses, slot_mesh,
+                          (0, 0, 0, 0, 1, 0, 0, 0, None), (0, 0, 0, 0, 1))
     drives = Q.to_fixed(s1_series, QUANT) if quant else s1_series
-    net, s2_series = engine.rollout(
-        net, [params["theta"].astype(jnp.float32)], drives,
-        params=ep, impl=cfg.adapter_impl, active=active,
-        seed=state["t"] if quant else None)
+    w_fast, v2, tr1, tr2, s2_series = synapses(
+        state["w_fast"], state["v2"], state["tr1"], state["tr2"], drives,
+        active, state["t"] if quant else None, state.get("w_scale"),
+        params["theta"].astype(jnp.float32))
 
     def post(_, s2):
         s2f = Q.from_fixed(s2, QUANT) if quant else s2
@@ -219,8 +249,8 @@ def decode_rollout(params, state: dict, h, cfg: ModelConfig,
         outs = outs * active.astype(jnp.float32)[None, :, None]
     h = h + (params["scale"] * jnp.swapaxes(outs, 0, 1)).astype(h.dtype)
     k_steps = h.shape[1]
-    new_state = {"w_fast": net.w[0], "v1": v1, "v2": net.v[0],
-                 "tr1": net.trace[0], "tr2": net.trace[1],
+    new_state = {"w_fast": w_fast, "v1": v1, "v2": v2,
+                 "tr1": tr1, "tr2": tr2,
                  "t": state["t"] + _gate(active,
                                          jnp.full((), k_steps, jnp.int32),
                                          jnp.zeros((), jnp.int32))}

@@ -482,7 +482,8 @@ def _head(params, h, cfg: ModelConfig):
     return shard_constraint(logits, ("data", None, "model"))
 
 
-def decode_step(params, cache, tokens, cfg: ModelConfig, active=None):
+def decode_step(params, cache, tokens, cfg: ModelConfig, active=None,
+                slot_mesh=None):
     """One decode step.  tokens (B,1) int32.
 
     Returns (logits (B,V), new_cache).  cache["index"] is the number of
@@ -490,19 +491,23 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, active=None):
     under the continuous-batching pool); the new token is written at that
     position.  ``active (B,)`` marks resident streams — vacant slots are
     no-ops: adapter state bit-frozen, per-slot index frozen, no expert
-    capacity consumed, logits garbage nothing reads.
+    capacity consumed, logits garbage nothing reads.  ``slot_mesh`` is the
+    mesh whose ``"data"`` axis shards a pool's slot rows (see
+    `plastic.decode_step`).
     """
     h, new_segs, new_index = _decode_backbone(params, cache, tokens, cfg,
                                               active=active)
     new_cache = {"segments": new_segs, "index": new_index}
     if cfg.plastic_adapter:
         h, new_cache["adapter"] = plastic.decode_step(
-            params["adapter"], cache["adapter"], h, cfg, active=active)
+            params["adapter"], cache["adapter"], h, cfg, active=active,
+            slot_mesh=slot_mesh)
     logits = _head(params, h, cfg)[:, 0]
     return shard_constraint(logits, ("data", "model")), new_cache
 
 
-def decode_rollout(params, cache, tokens, cfg: ModelConfig, active=None):
+def decode_rollout(params, cache, tokens, cfg: ModelConfig, active=None,
+                   slot_mesh=None):
     """K known tokens per stream in one jitted program.  tokens (B,K) int32.
 
     Teacher-forced multi-token decode — chunked prompt tails, speculative
@@ -518,7 +523,7 @@ def decode_rollout(params, cache, tokens, cfg: ModelConfig, active=None):
     calls on the same tokens (pinned in tests/test_serving_lm.py).
 
     Returns (logits (B,K,V), new_cache).  Works for every layout, with or
-    without the adapter.
+    without the adapter; ``slot_mesh`` as in `decode_step`.
     """
     tk = jnp.swapaxes(tokens, 0, 1)[:, :, None]          # (K,B,1)
 
@@ -535,7 +540,8 @@ def decode_rollout(params, cache, tokens, cfg: ModelConfig, active=None):
     new_cache = {"segments": new_segs, "index": new_index}
     if cfg.plastic_adapter:
         h, new_cache["adapter"] = plastic.decode_rollout(
-            params["adapter"], cache["adapter"], h, cfg, active=active)
+            params["adapter"], cache["adapter"], h, cfg, active=active,
+            slot_mesh=slot_mesh)
     return _head(params, h, cfg), new_cache
 
 

@@ -22,8 +22,7 @@ event log and the CI obs-smoke job uploads as an artifact).
 
 `phase(name)` annotates a host-side serving phase (admit, swap-in/out, pool
 step, decode window) with `jax.profiler.TraceAnnotation`, so device
-profiles attribute time to scheduling events; it degrades to a no-op
-timer-only context when the profiler is unavailable.
+profiles attribute time to scheduling events.
 
 `serve_metrics(registry, port)` exposes a registry over stdlib
 `http.server` for scraping (`serve.py --metrics-port`): every metric holds
@@ -39,6 +38,8 @@ import time
 from contextlib import contextmanager
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Iterable, Optional
+
+import jax
 
 # Default le-buckets: 100 us .. ~100 s in half-decade steps — spans warm
 # admissions (sub-ms), disk restores (ms..tens of ms), and decode windows.
@@ -259,20 +260,11 @@ def phase(name: str, histogram: Optional[Histogram] = None):
     Wraps the block in `jax.profiler.TraceAnnotation(name)` so device
     profiles attribute time to scheduling events (admit, swap_in, swap_out,
     pool_step, decode_window); if a `Histogram` is given the block's
-    wall-clock duration is observed into it.  Profiler-free environments
-    degrade to the timer alone.
+    wall-clock duration is observed into it.
     """
     t0 = time.perf_counter()
     try:
-        from jax.profiler import TraceAnnotation
-        ctx = TraceAnnotation(name)
-    except Exception:           # pragma: no cover - profiler unavailable
-        ctx = None
-    try:
-        if ctx is not None:
-            with ctx:
-                yield
-        else:                   # pragma: no cover
+        with jax.profiler.TraceAnnotation(name):
             yield
     finally:
         if histogram is not None:

@@ -65,8 +65,13 @@ class LMScheduler(SessionPool):
                constrained jit (GSPMD), NOT shard_map: the MoE capacity/
                cumsum stages reduce ACROSS slots, and GSPMD partitions them
                without changing their semantics, where a manual per-shard
-               lowering would.  Token streams are device-count invariant
-               (tests/test_distributed.py pins the parity).
+               lowering would.  Only the plastic adapter's engine launch
+               runs per shard (`engine.fleet_spmd`): its slot rows are
+               independent, and GSPMD cannot partition a Pallas kernel.
+               The partitioned program is not the unmeshed one, so bf16
+               logits may differ by roundings and a greedy token flips
+               where the top two logits are that close; at smoke widths
+               the token streams agree (tests/test_distributed.py).
     """
 
     def __init__(self, model, params, slots: int, max_len: int,
@@ -110,7 +115,8 @@ class LMScheduler(SessionPool):
             # no-ops end to end (cache index held, adapter frozen, pending
             # token carried through)
             logits, cache = model.decode_step(
-                params, pool["cache"], pool["tok"][:, None], active=active)
+                params, pool["cache"], pool["tok"][:, None], active=active,
+                slot_mesh=mesh)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return (_constrain({"cache": cache,
                                 "tok": jnp.where(active, nxt, pool["tok"])}),
@@ -121,7 +127,7 @@ class LMScheduler(SessionPool):
             # backbone scans token-by-token, the adapter runs K plasticity
             # steps as a single time-fused plastic.decode_rollout
             logits, cache = model.decode_rollout(
-                params, pool["cache"], tokens, active=active)
+                params, pool["cache"], tokens, active=active, slot_mesh=mesh)
             nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             return (_constrain({"cache": cache,
                                 "tok": jnp.where(active, nxt, pool["tok"])}),

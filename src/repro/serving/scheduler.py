@@ -897,7 +897,7 @@ class FleetScheduler(SessionPool):
             # Lower `core` under shard_map over the slot axis
             # (`engine.fleet_spmd`): the NetworkState is flattened into its
             # slot-mapped fields; the pool clock `t` rides in REPLICATED
-            # (shard_map with check_rep=False cannot return an unmapped
+            # (shard_map with check_vma=False cannot return an unmapped
             # output, and Pallas carries no replication rule) and advances
             # OUTSIDE the mapped region — bit-exactly what the unmeshed
             # step computes, since `t` only feeds the t+k bump here (the

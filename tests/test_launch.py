@@ -12,7 +12,7 @@ import pytest
 from repro.configs import ARCHS
 from repro.distributed import sharding as shd
 from repro.launch import steps
-from repro.launch.mesh import HW, make_local_mesh
+from repro.launch.mesh import V5E, make_local_mesh, peaks
 from repro.launch.specs import TRAIN_SETUP, input_specs
 from repro.models.config import SHAPES
 from repro.optim import adamw
@@ -28,9 +28,40 @@ def test_train_setup_covers_every_arch():
 
 
 def test_hw_constants():
-    assert HW["peak_flops_bf16"] == 197e12
-    assert HW["hbm_bw"] == 819e9
-    assert HW["hbm_bytes"] == 16 * 2**30
+    hw = peaks(V5E)
+    assert hw["peak_flops_bf16"] == 197e12
+    assert hw["hbm_bw"] == 819e9
+    assert hw["hbm_bytes"] == 16 * 2**30
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The env var wins and nothing else is set; unset, the fixed
+    <repo>/.jax_cache."""
+    import os
+
+    from repro.launch import cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cache.enable_compile_cache()
+        assert path == os.path.join(cache.REPO_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert os.path.isfile(os.path.join(cache.REPO_ROOT, "chip_smoke.py"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_local_mesh_is_auto_and_refuses_missing_devices():
+    from jax.sharding import AxisType
+    mesh = make_local_mesh()
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(data=len(jax.devices()) + 1)
 
 
 @pytest.mark.parametrize("arch", ["qwen3-4b", "deepseek-moe-16b",

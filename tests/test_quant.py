@@ -161,10 +161,17 @@ class TestQuantBackendBitParity:
                      (po, ps.w, ps.v, ps.trace_post))
         # a coarser scale means the same fixed psum maps to larger currents:
         # slot dynamics must actually DIFFER across scales for equal payloads
+        # (every other per-slot operand equal too; the membrane carries the
+        # scaled current, where binary spikes may saturate alike)
+        row0 = lambda a: jnp.broadcast_to(a[0], a.shape)
         state_eq = dataclasses.replace(
-            state, w=jnp.broadcast_to(state.w[0], state.w.shape))
-        _, o_eq = self._run(state_eq, jnp.broadcast_to(x[:1], x.shape), "xla")
-        assert not np.array_equal(np.asarray(o_eq[0]), np.asarray(o_eq[1]))
+            state, w=row0(state.w), v=row0(state.v),
+            trace_pre=row0(state.trace_pre),
+            trace_post=row0(state.trace_post))
+        s_eq, _ = self._run(state_eq, row0(x), "xla")
+        v_eq = np.asarray(s_eq.v)
+        assert not np.array_equal(v_eq[0], v_eq[1])
+        assert not np.array_equal(v_eq[0], v_eq[2])
 
     def test_plastic_off_passes_weights_through(self):
         state, x = _qlayer(jax.random.PRNGKey(13), 3, 16, 16, fleet=True,

@@ -1,0 +1,1 @@
+"""The chip benchmark: `python3 bench/run.py --workload <cell> ...`."""

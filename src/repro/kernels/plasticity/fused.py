@@ -443,7 +443,8 @@ def rollout_pallas(drives, ws, thetas, vs, traces, *, spiking, plastic,
         telemetry=telemetry)
     res = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*operands)
+        out_shape=out_shape, interpret=interpret,
+        name="plasticity_fused_rollout")(*operands)
     outs = res[0]
     ws_new = tuple(res[1:1 + n_layers])
     vs_new = tuple(res[1 + n_layers:1 + 2 * n_layers])

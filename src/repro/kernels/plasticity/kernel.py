@@ -272,6 +272,7 @@ def dual_engine_step_pallas(x, w, theta, v, trace_pre, trace_post, *,
             jax.ShapeDtypeStruct((n, m), w.dtype),
         ],
         interpret=interpret,
+        name="plasticity_step",
     )(*operands)
 
 
@@ -383,10 +384,10 @@ def _fleet_out(b, n, m, bm, bb, *, s_dtype, v_dtype, tp_dtype, w_dtype,
 
 
 def _fleet_call(kernel, operands, in_specs, out_specs, out_shape, *, grid,
-                telemetry, interpret):
+                telemetry, interpret, name):
     res = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*operands)
+        out_shape=out_shape, interpret=interpret, name=name)(*operands)
     if not telemetry:
         return res
     # Fold the tile axis of the partials -> raw (B, 3) telemetry row.
@@ -452,7 +453,8 @@ def dual_engine_fleet_step_pallas(x, w, theta, v, trace_pre, trace_post, *,
         b, n, m, bm, bb, s_dtype=x.dtype, v_dtype=v.dtype,
         tp_dtype=trace_post.dtype, w_dtype=w.dtype, telemetry=telemetry)
     return _fleet_call(kernel, operands, in_specs, out_specs, out_shape,
-                       grid=grid, telemetry=telemetry, interpret=interpret)
+                       grid=grid, telemetry=telemetry, interpret=interpret,
+                       name="plasticity_fleet_step")
 
 
 # ---- fixed-point (quantized) kernels ---------------------------------------
@@ -590,6 +592,7 @@ def dual_engine_step_q_pallas(x, w, scale, theta, v, trace_pre, trace_post,
             jax.ShapeDtypeStruct((n, m), jnp.int8),
         ],
         interpret=interpret,
+        name="plasticity_step_q",
     )(*operands)
 
 
@@ -691,4 +694,5 @@ def dual_engine_fleet_step_q_pallas(x, w, scale, theta, v, trace_pre,
         b, n, m, bm, bb, s_dtype=jnp.int32, v_dtype=jnp.int32,
         tp_dtype=jnp.int32, w_dtype=jnp.int8, telemetry=telemetry)
     return _fleet_call(kernel, operands, in_specs, out_specs, out_shape,
-                       grid=grid, telemetry=telemetry, interpret=interpret)
+                       grid=grid, telemetry=telemetry, interpret=interpret,
+                       name="plasticity_fleet_step_q")

@@ -20,9 +20,28 @@ process-global singleton, so two pools in one process never alias counters;
 dict — the schema `benchmarks/serving_churn.py` reconciles against its own
 event log and the CI obs-smoke job uploads as an artifact).
 
-`phase(name)` annotates a host-side serving phase (admit, swap-in/out, pool
-step, decode window) with `jax.profiler.TraceAnnotation`, so device
-profiles attribute time to scheduling events.
+`phase(name)` opens a host span: a `jax.profiler.TraceAnnotation` that a
+profiler trace records on the same clock as the device's ops, and that
+costs one check of whether a trace is on when none is.  The spans:
+
+  * ``pool.admit`` (``pool.swap_in`` inside), ``pool.evict`` (inside it
+    ``pool.swap_out``, ``pool.persist``: the move to the `SessionStore`,
+    ``pool.clear``: the zero scatter over the vacated slot) and
+    ``pool.drain``: `SessionPool`;
+  * ``pool.pack`` (the drives into slot order), ``pool.step`` or
+    ``pool.rollout`` (the dispatch of the pool program) and ``pool.unpack``
+    (the step counters and the per-session output slices): each
+    `FleetScheduler.step` and `pool_step` call, once each;
+  * ``lm.decode_step`` and ``lm.decode_window``: `LMScheduler`;
+  * ``serve.prefill``, ``serve.decode_step`` (`launch/serve.py`) and
+    ``scenario.rollout`` (`scenarios/harness.py`).
+
+The benchmark keeps the ``pool.`` and ``lm.`` spans (`bench/trace.py`):
+its per-layer readers under `bench/metrics/` subtract ``pool.rollout``
+from the client's call (``sched_host_ms``) and count the device's idle
+time in ``lm.decode_step`` (``sched_host_ms.lm``); every traced run names
+its idle gaps by the innermost of them, so the time in ``pool.pack``,
+``pool.unpack``, ``pool.persist`` and ``pool.clear`` shows there by name.
 
 `serve_metrics(registry, port)` exposes a registry over stdlib
 `http.server` for scraping (`serve.py --metrics-port`): every metric holds
@@ -37,7 +56,7 @@ import threading
 import time
 from contextlib import contextmanager
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Dict, Iterable, Optional
+from typing import Dict, Iterable
 
 import jax
 
@@ -253,22 +272,10 @@ class MetricsRegistry:
 REGISTRY = MetricsRegistry()     # default registry for one-off scripts
 
 
-@contextmanager
-def phase(name: str, histogram: Optional[Histogram] = None):
-    """Annotate a serving phase for profilers (+ optional latency record).
-
-    Wraps the block in `jax.profiler.TraceAnnotation(name)` so device
-    profiles attribute time to scheduling events (admit, swap_in, swap_out,
-    pool_step, decode_window); if a `Histogram` is given the block's
-    wall-clock duration is observed into it.
-    """
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        if histogram is not None:
-            histogram.observe(time.perf_counter() - t0)
+def phase(name: str) -> jax.profiler.TraceAnnotation:
+    """The host span `name` (see the module docstring for the spans that
+    exist and what reads them), for use as ``with phase(name):``."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 # ---- scrape endpoint --------------------------------------------------------

@@ -442,12 +442,14 @@ class SessionPool:
             with phase("pool.swap_out"):
                 user = self._take(self.pool, jnp.int32(slot))
             user = self._finalize_session(user, int(self._steps[slot]))
-            self.store.checkin(uid, user, int(self._steps[slot]))
+            with phase("pool.persist"):
+                self.store.checkin(uid, user, int(self._steps[slot]))
             self.slot_user[slot] = None
             # hygiene: scatter zeros over the vacated slot so no stale user
             # data lingers in the pool tensor (the mask already freezes it)
-            self.pool = self._put(self.pool, jnp.int32(slot),
-                                  self._zero_session)
+            with phase("pool.clear"):
+                self.pool = self._put(self.pool, jnp.int32(slot),
+                                      self._zero_session)
         self._steps[slot] = 0
         if self._rec is not None:
             self._rec = self._reset_rec(self._rec, jnp.int32(slot))
@@ -1033,6 +1035,16 @@ class FleetScheduler(SessionPool):
             tarr = jnp.asarray(tarr)
         return jnp.asarray(drive), tarr
 
+    def _unpack(self, outs: jax.Array, k: int,
+                slot_axis: int) -> Dict[str, jax.Array]:
+        """Advance the step counters by `k` and hand each admitted session
+        its slice of `outs` along `slot_axis` (the span ``pool.unpack``)."""
+        lead = (slice(None),) * slot_axis
+        with phase("pool.unpack"):
+            self.advance_steps(k)
+            return {uid: outs[lead + (slot,)]
+                    for uid, slot in self.user_slot.items()}
+
     def step(self, drives: Mapping[str, jax.Array],
              teach: Optional[Mapping[str, jax.Array]] = None,
              telemetry: bool = False, record: bool = False):
@@ -1056,7 +1068,8 @@ class FleetScheduler(SessionPool):
         ``telemetry=True`` too to ALSO get the host-side tuple return and
         summary gauges (same single program either way).
         """
-        drive, tarr = self._gather_rows(drives, teach)
+        with phase("pool.pack"):
+            drive, tarr = self._gather_rows(drives, teach)
         if record:
             rec = self._ensure_recorder()
             with phase("pool.step"):
@@ -1073,8 +1086,7 @@ class FleetScheduler(SessionPool):
                 res = fn(self.fleet, drive, self._active_mask(), tarr,
                          jnp.asarray(self._steps.astype(np.int32)))
             self.fleet, out = res[0], res[1]
-        self.advance_steps(1)
-        outputs = {uid: out[slot] for uid, slot in self.user_slot.items()}
+        outputs = self._unpack(out, 1, slot_axis=0)
         if not telemetry:
             return outputs
         tel: FleetTelemetry = res[2]
@@ -1113,9 +1125,10 @@ class FleetScheduler(SessionPool):
         k = self.cfg.timesteps if timesteps is None else int(timesteps)
         if k < 1:
             raise ValueError(f"pool_step needs timesteps >= 1, got {k}")
-        drive, tarr = self._gather_rows(drives, teach)
         n_in = self.cfg.layer_sizes[0]
-        window = jnp.broadcast_to(drive[None], (k, self.slots, n_in))
+        with phase("pool.pack"):
+            drive, tarr = self._gather_rows(drives, teach)
+            window = jnp.broadcast_to(drive[None], (k, self.slots, n_in))
         if record:
             rec = self._ensure_recorder()
             with phase("pool.rollout"):
@@ -1132,8 +1145,7 @@ class FleetScheduler(SessionPool):
                 res = fn(self.fleet, window, self._active_mask(), tarr,
                          jnp.asarray(self._steps.astype(np.int32)))
             self.fleet, outs = res[0], res[1]
-        self.advance_steps(k)
-        outputs = {uid: outs[:, slot] for uid, slot in self.user_slot.items()}
+        outputs = self._unpack(outs, k, slot_axis=1)
         if not telemetry:
             return outputs
         tel: FleetTelemetry = res[2]

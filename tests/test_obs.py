@@ -19,8 +19,15 @@ The contracts this file locks down (see src/repro/obs/ and DESIGN.md):
      name.
   6. SessionStore's legacy counter attributes (warm_hits/restores/creates/
      persists) are live views of the obs counters — one source of truth.
+  7. The scheduler's host spans, read back from a recorded profiler trace:
+     each `FleetScheduler` call opens ``pool.pack``, its dispatch span and
+     ``pool.unpack`` once, in that order, whatever the number of sessions;
+     ``pool.swap_out``, ``pool.persist`` and ``pool.clear`` lie inside
+     ``pool.evict``; and tracing compiles no program.
 """
+import glob
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +36,8 @@ import pytest
 
 from repro.core import engine, snn
 from repro.kernels.plasticity import quant as Q
-from repro.obs import (FleetTelemetry, MetricsRegistry, SAT_FRACTION,
-                       adapter_telemetry, record_fleet_telemetry,
+from repro.obs import (FleetTelemetry, HealthConfig, MetricsRegistry,
+                       SAT_FRACTION, adapter_telemetry, record_fleet_telemetry,
                        watchdog as watch)
 from repro.serving import FleetScheduler, SessionStore
 
@@ -296,11 +303,11 @@ class TestAdapterTelemetry:
 # scheduler integration: compile audit + recorded gauges
 # ---------------------------------------------------------------------------
 
-def _sched(impl="xla", slots=3):
+def _sched(impl="xla", slots=3, health=None):
     cfg = snn.SNNConfig(layer_sizes=(8, 12, 4), timesteps=3, plastic=True,
                         encoding="current", impl=impl)
     theta = snn.init_theta(cfg, jax.random.PRNGKey(0), scale=0.05)
-    return FleetScheduler(cfg, theta, slots=slots)
+    return FleetScheduler(cfg, theta, slots=slots, health=health)
 
 
 class TestSchedulerObs:
@@ -352,6 +359,86 @@ class TestSchedulerObs:
         assert snap["pool_evictions_total"]["value"] == 1
         assert snap["pool_occupancy"]["value"] == pytest.approx(1 / 3)
         assert snap["pool_admit_seconds"]["count"] == 2
+
+
+def _traced_spans(trace_dir, fn):
+    """Run `fn` under the profiler; return its ``pool.*`` host spans as
+    (start_ns, end_ns, name), by start."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    return sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("pool."))
+
+
+class TestSchedulerSpans:
+    @pytest.mark.parametrize("method,dispatch",
+                             [("pool_step", "pool.rollout"),
+                              ("step", "pool.step")])
+    # telemetry off, telemetry on, and the flight-recorder variant
+    @pytest.mark.parametrize("telemetry", [False, True, "record"])
+    def test_pack_dispatch_unpack_once_per_call_in_order(
+            self, tmp_path, method, dispatch, telemetry):
+        """Traced calls open three spans each, whatever the pool's size,
+        and give bitwise the outputs of untraced calls on a twin pool,
+        with the same programs compiled."""
+        record = telemetry == "record"
+        health = HealthConfig(z_threshold=1e9, bounds=((-1e9, 1e9),) * 4,
+                              dead_floor=-1.0, hysteresis=(9999,) * 4)
+        traced, plain = (_sched(slots=4, health=health if record else None)
+                         for _ in range(2))
+        for sched in (traced, plain):
+            for u in ("u0", "u1", "u2"):
+                sched.admit(u)
+        drives = {u: np.full(8, 1.5, np.float32) for u in ("u0", "u1", "u2")}
+        kw = {"record": True} if record else {"telemetry": telemetry}
+        calls = 3
+
+        def run(sched):
+            call = getattr(sched, method)
+            outs = [call(drives, **kw) for _ in range(calls)]
+            return [o[0] if telemetry is True else o for o in outs]
+
+        want = run(plain)
+        got = []
+        spans = _traced_spans(tmp_path, lambda: got.extend(run(traced)))
+        assert [n for _, _, n in spans] == \
+            ["pool.pack", dispatch, "pool.unpack"] * calls
+        for (_, end, _), (start, _, _) in zip(spans, spans[1:]):
+            assert end <= start
+        for g, w in zip(got, want):
+            assert sorted(g) == sorted(w) == ["u0", "u1", "u2"]
+            for u in g:
+                np.testing.assert_array_equal(_np(g[u]), _np(w[u]))
+        assert traced.compiled_programs() == plain.compiled_programs()
+
+    def test_evict_children_lie_inside_evict(self, tmp_path):
+        sched = _sched(slots=3)
+        for u in ("a", "b", "c"):
+            sched.admit(u)
+        sched.evict("c")                                # compiles
+        progs = sched.compiled_programs()
+        spans = _traced_spans(tmp_path, lambda: sched.evict("a"))
+        assert [n for _, _, n in spans] == \
+            ["pool.evict", "pool.swap_out", "pool.persist", "pool.clear"]
+        (lo, hi, _), *children = spans
+        for (start, end, _), nxt in zip(children, children[1:] + [None]):
+            assert lo <= start <= end <= hi
+            if nxt is not None:
+                assert end <= nxt[0]
+        assert sched.compiled_programs() == progs
+        assert sched.store.known("a")
 
 
 class TestSessionStoreMetrics:

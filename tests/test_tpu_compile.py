@@ -122,9 +122,20 @@ CASES = {
 }
 
 
+def _kernel_name(case):
+    """The stable name the case's `pallas_call` gives its kernel: the HLO
+    instruction's name, and so the op's name in a chip trace."""
+    if case.startswith("fused-"):
+        return "plasticity_fused_rollout"
+    if case.startswith("shared-"):
+        return "plasticity_step"
+    return "plasticity_fleet_step" + ("_q" if "-int8-" in case else "")
+
+
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{_kernel_name(case)}." in text

@@ -30,7 +30,8 @@ costs one check of whether a trace is on when none is.  The spans:
     ``pool.drain``: `SessionPool`;
   * ``pool.pack`` (the drives into slot order), ``pool.step`` or
     ``pool.rollout`` (the dispatch of the pool program) and ``pool.unpack``
-    (the step counters and the per-session output slices): each
+    (the step counters and the one launch that splits the outputs into
+    per-session arrays): each
     `FleetScheduler.step` and `pool_step` call, once each;
   * ``lm.decode_step`` and ``lm.decode_window``: `LMScheduler`;
   * ``serve.prefill``, ``serve.decode_step`` (`launch/serve.py`) and

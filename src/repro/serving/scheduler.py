@@ -979,6 +979,25 @@ class FleetScheduler(SessionPool):
         # at B=256, measured by benchmarks/obs_health.py)
         self._step_rec = jax.jit(_pool_step_rec)
         self._rollout_rec = jax.jit(_pool_rollout_rec)
+
+        def _pool_unpack(outs, slot_axis, actions):
+            # the call's output array split into one array per slot in ONE
+            # program (vacant, lost and quarantined slots included: the
+            # shape is fixed), so handing sessions their rows costs no
+            # device op per uid.  `actions` first takes the control step's
+            # window mean over the whole pool, tanh-squashed unless the
+            # readout spikes (`snn.controller_step`)
+            if actions:
+                outs = outs.mean(axis=0)
+                if not cfg.spiking_readout:
+                    outs = jnp.tanh(outs)
+                slot_axis -= 1
+            return jnp.unstack(outs, axis=slot_axis)
+
+        # compiles once per output shape and sharding (the step's (B, act),
+        # the rollout's (K, B, act)), in warm-up like the pool programs
+        self._split = jax.jit(_pool_unpack,
+                              static_argnames=("slot_axis", "actions"))
         self._jitted.update({
             "pool_step": self._step,
             "pool_rollout": self._rollout,
@@ -986,6 +1005,7 @@ class FleetScheduler(SessionPool):
             "pool_rollout_telemetry": self._rollout_tel,
             "pool_step_record": self._step_rec,
             "pool_rollout_record": self._rollout_rec,
+            "pool_unpack": self._split,
         })
 
     # the historical attribute name: the pool pytree IS the fleet state
@@ -1035,15 +1055,16 @@ class FleetScheduler(SessionPool):
             tarr = jnp.asarray(tarr)
         return jnp.asarray(drive), tarr
 
-    def _unpack(self, outs: jax.Array, k: int,
-                slot_axis: int) -> Dict[str, jax.Array]:
+    def _unpack(self, outs: jax.Array, k: int, slot_axis: int,
+                actions: bool = False) -> Dict[str, jax.Array]:
         """Advance the step counters by `k` and hand each admitted session
-        its slice of `outs` along `slot_axis` (the span ``pool.unpack``)."""
-        lead = (slice(None),) * slot_axis
+        its slice of `outs` along `slot_axis` (the span ``pool.unpack``),
+        all split from `outs` by one launch of ``pool_unpack``; ``actions``
+        hands out control actions instead (see `control_step`)."""
         with phase("pool.unpack"):
             self.advance_steps(k)
-            return {uid: outs[lead + (slot,)]
-                    for uid, slot in self.user_slot.items()}
+            rows = self._split(outs, slot_axis=slot_axis, actions=actions)
+            return {uid: rows[slot] for uid, slot in self.user_slot.items()}
 
     def step(self, drives: Mapping[str, jax.Array],
              teach: Optional[Mapping[str, jax.Array]] = None,
@@ -1122,6 +1143,20 @@ class FleetScheduler(SessionPool):
         a recorded window is one observation, matching the per-step path's
         cadence in recorded samples per launch.
         """
+        res, k = self._run_window(drives, timesteps, teach, telemetry, record)
+        outputs = self._unpack(res[1], k, slot_axis=1)
+        if not telemetry:
+            return outputs
+        tel: FleetTelemetry = res[2]
+        record_fleet_telemetry(self.metrics, tel)
+        return outputs, tel
+
+    def _run_window(self, drives: Mapping[str, jax.Array],
+                    timesteps: Optional[int],
+                    teach: Optional[Mapping[str, jax.Array]],
+                    telemetry: bool, record: bool) -> tuple:
+        """Pack and dispatch one `pool_step` window; returns the pool
+        program's outputs (the new pool state already installed) and K."""
         k = self.cfg.timesteps if timesteps is None else int(timesteps)
         if k < 1:
             raise ValueError(f"pool_step needs timesteps >= 1, got {k}")
@@ -1136,7 +1171,6 @@ class FleetScheduler(SessionPool):
                     self.fleet, window, self._active_mask(), tarr,
                     jnp.asarray(self._steps.astype(np.int32)),
                     rec, jnp.int32(self._rec_pos))
-            self.fleet, outs = res[0], res[1]
             self._rec, self.last_verdict = res[3], res[4]
             self._rec_pos += 1
         else:
@@ -1144,13 +1178,8 @@ class FleetScheduler(SessionPool):
             with phase("pool.rollout"):
                 res = fn(self.fleet, window, self._active_mask(), tarr,
                          jnp.asarray(self._steps.astype(np.int32)))
-            self.fleet, outs = res[0], res[1]
-        outputs = self._unpack(outs, k, slot_axis=1)
-        if not telemetry:
-            return outputs
-        tel: FleetTelemetry = res[2]
-        record_fleet_telemetry(self.metrics, tel)
-        return outputs, tel
+        self.fleet = res[0]
+        return res, k
 
     def control_step(self, obs: Mapping[str, jax.Array]
                      ) -> Dict[str, jax.Array]:
@@ -1158,10 +1187,7 @@ class FleetScheduler(SessionPool):
         observations (mirrors `snn.controller_step`: mean readout over the
         window, tanh-squashed unless the readout spikes).  The window runs
         as ONE fused `pool_step` launch instead of ``timesteps`` separate
-        pool steps."""
-        outs = self.pool_step(obs)
-        actions = {}
-        for uid, window in outs.items():
-            a = window.mean(axis=0)
-            actions[uid] = a if self.cfg.spiking_readout else jnp.tanh(a)
-        return actions
+        pool steps, and the actions of every session come out of one
+        ``pool_unpack`` launch."""
+        res, k = self._run_window(obs, None, None, False, False)
+        return self._unpack(res[1], k, slot_axis=1, actions=True)

@@ -403,6 +403,37 @@ class TestMultiDevice:
                               m.pool_step(dict(dd), timesteps=3))
         _assert_pools_equal(ref, m)
 
+    @pytest.mark.parametrize("datapath", DATAPATHS)
+    @pytest.mark.parametrize("d", [2, 4])
+    def test_unpack_is_the_program_array_at_each_slot(self, d, datapath):
+        """The one-launch split of a sharded call's output: every uid's
+        array is bitwise the meshed program's output at its slot, and the
+        unmeshed pool's."""
+        users = [f"u{i}" for i in range(6)]
+        ref = _sched("xla", datapath, slots=8)
+        m = _sched("xla", datapath, slots=8, mesh=dsh.fleet_mesh(d))
+        seen = []
+        for name in ("_step", "_rollout"):
+            def spy(*args, _real=getattr(m, name)):
+                res = _real(*args)
+                seen.append(res[1])
+                return res
+            setattr(m, name, spy)
+        for s in (ref, m):
+            for u in users:
+                s.admit(u)
+            s.evict("u2")
+        for call, slot_axis, kw in (("step", 0, {}),
+                                    ("pool_step", 1, {"timesteps": 3})):
+            dd = {u: _drive(u, 7) for u in ref.active_users}
+            want, got = (getattr(s, call)(dict(dd), **kw) for s in (ref, m))
+            _assert_outputs_equal(want, got)
+            arr = np.asarray(seen[-1])
+            for u, slot in m.user_slot.items():
+                np.testing.assert_array_equal(
+                    np.asarray(got[u]), np.take(arr, slot, axis=slot_axis))
+        assert m.compiled_programs()["pool_unpack"] == 2
+
     def test_zero_recompiles_under_churn(self):
         m = _sched("xla", "float32", slots=8, mesh=dsh.fleet_mesh(4))
         users = [f"u{i}" for i in range(6)]
@@ -666,3 +697,16 @@ class TestForcedMultiDeviceSubprocess:
                               env=env)
         assert proc.returncode == 0, proc.stderr
         assert "multidevice-ok" in proc.stdout
+
+    def test_meshed_unpack_on_four_host_devices(self):
+        """The multi-device cells of the one-launch output split, run from
+        tier-1 in a process with 4 forced host devices."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
+        proc = subprocess.run(
+            [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+             "-p", "no:xdist", os.path.abspath(__file__),
+             "-k", "test_unpack_is_the_program_array_at_each_slot"],
+            capture_output=True, text=True, timeout=600, env=env)
+        assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr
+        assert "4 passed" in proc.stdout, proc.stdout[-3000:]

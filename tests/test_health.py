@@ -429,7 +429,8 @@ class TestIncidentDrill:
             "slot_put": 1, "slot_take": 1, "recorder_reset": 1,
             "pool_step": 0, "pool_rollout": 0,
             "pool_step_telemetry": 0, "pool_rollout_telemetry": 0,
-            "pool_step_record": 0, "pool_rollout_record": 1}
+            "pool_step_record": 0, "pool_rollout_record": 1,
+            "pool_unpack": 1}
 
         # incident bundle: JSON + NPZ post-mortem
         doc = json.load(open(reports[0]["incident"]))

@@ -320,7 +320,8 @@ class TestSchedulerObs:
                               "pool_step", "pool_rollout",
                               "pool_step_telemetry",
                               "pool_rollout_telemetry",
-                              "pool_step_record", "pool_rollout_record"}
+                              "pool_step_record", "pool_rollout_record",
+                              "pool_unpack"}
         assert progs["pool_step_telemetry"] == 0
         sched.admit("u0")
         drives = {"u0": np.ones(8, np.float32)}
@@ -330,6 +331,7 @@ class TestSchedulerObs:
         progs = sched.compiled_programs()
         assert progs["pool_step"] == 1
         assert progs["pool_step_telemetry"] == 1
+        assert progs["pool_unpack"] == 1        # one (B, act) output shape
         assert sched.compile_count() == sum(progs.values())
 
     def test_step_telemetry_records_gauges(self):

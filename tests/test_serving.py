@@ -385,3 +385,155 @@ class TestFleetScheduler:
         _, a_ref = snn.controller_step(cfg, ref_state, theta, obs[None])
         np.testing.assert_allclose(a_pool, np.asarray(a_ref[0]),
                                    rtol=1e-6, atol=1e-6)
+
+
+# the pool programs whose output array `FleetScheduler._unpack` splits
+_POOL_PROGRAMS = ("_step", "_rollout", "_step_tel", "_rollout_tel",
+                  "_step_rec", "_rollout_rec")
+
+
+def _spy_outputs(sched):
+    """Wrap every pool program of `sched` so that each call's output array
+    (the one the scheduler splits per session) is appended to the list
+    returned; the compile audit still reads the real programs."""
+    seen = []
+    for name in _POOL_PROGRAMS:
+        def spy(*args, _real=getattr(sched, name)):
+            res = _real(*args)
+            seen.append(res[1])
+            return res
+        setattr(sched, name, spy)
+    return seen
+
+
+class TestOneLaunchUnpack:
+    """`_unpack` splits a call's output array into per-slot arrays in one
+    compiled program (``pool_unpack``): pure data movement, so every
+    session's output is bitwise the program's array at its slot."""
+
+    def _sched(self, datapath="float32", slots=6, health=None,
+               spiking_readout=False):
+        cfg = snn.SNNConfig(layer_sizes=(6, 12, 4), timesteps=3,
+                            spiking_readout=spiking_readout)
+        if datapath == "int8":
+            cfg = snn.quant_config(cfg)
+        # a rule strong enough that readouts reach O(1) within a few
+        # windows, so a wrong slot or a missing tanh shows
+        theta = snn.init_theta(cfg, jax.random.PRNGKey(0), scale=0.2)
+        return FleetScheduler(cfg, theta, slots=slots, store=SessionStore(),
+                              health=health)
+
+    @staticmethod
+    def _drives(sched, t):
+        """A distinct drive per session (the uid's last character sets its
+        phase)."""
+        return {u: 2.0 * np.sin(0.7 * t + 1.3 * ord(u[-1])
+                                + np.arange(6)).astype(np.float32)
+                for u in sched.active_users}
+
+    @pytest.mark.parametrize("datapath", ["float32", "int8"])
+    @pytest.mark.parametrize("variant", ["plain", "telemetry", "record"])
+    @pytest.mark.parametrize("method,slot_axis",
+                             [("step", 0), ("pool_step", 1)])
+    def test_outputs_are_the_program_array_at_each_slot(
+            self, method, slot_axis, variant, datapath):
+        """Partial occupancy: a vacant, a lost and a quarantined slot
+        beside healthy ones; every admitted uid (stranded and quarantined
+        ones too) gets bitwise its slot of the program's output."""
+        from repro.obs.health import HealthConfig
+        s = self._sched(datapath, health=HealthConfig())
+        seen = _spy_outputs(s)
+        for u in ("a", "b", "c", "d", "e", "f"):
+            s.admit(u)
+        for t in range(3):                       # readouts grow to O(1)
+            s.pool_step(self._drives(s, t))
+        s.evict("b")                                   # slot 1 vacant
+        assert s.fail_slots([s.user_slot["c"]]) == ["c"]
+        s.quarantine("d")
+        kw = {"plain": {}, "telemetry": {"telemetry": True},
+              "record": {"record": True}}[variant]
+        for t in range(3, 5):
+            got = getattr(s, method)(self._drives(s, t), **kw)
+            if variant == "telemetry":
+                got = got[0]
+            arr = np.asarray(seen[-1])
+            assert sorted(got) == ["a", "c", "d", "e", "f"]
+            for u, slot in s.user_slot.items():
+                want = np.take(arr, slot, axis=slot_axis)
+                assert got[u].dtype == want.dtype
+                np.testing.assert_array_equal(np.asarray(got[u]), want)
+            # the healthy sessions' rows differ from each other and from
+            # the frozen slots', so a row handed to the wrong uid shows
+            healthy = [np.asarray(got[u]).tobytes() for u in ("a", "e", "f")]
+            assert len(set(healthy)) == 3
+            assert np.abs(np.asarray(got["e"])).max() > 0.1
+        # one program per output shape: the warm-up windows' and, for
+        # `step`, its own
+        assert s.compiled_programs()["pool_unpack"] == 1 + (method == "step")
+
+    def test_one_program_per_output_shape_and_none_under_churn(self):
+        """Occupancy changes every call; after the first call of each
+        entry point nothing compiles, and ``pool_unpack`` holds one
+        program per kind of output: the step's (B, act), the window's
+        (K, B, act) and the control step's actions."""
+        from repro.obs.watchdog import watchdog as watch
+        s = self._sched(slots=4)
+        s.admit("w")
+        s.evict("w")
+        s.admit("w")
+
+        def drives(t):
+            return self._drives(s, t)
+
+        s.step(drives(0))
+        s.pool_step(drives(0))
+        s.control_step(drives(0))
+        progs = s.compiled_programs()
+        assert progs["pool_unpack"] == 3
+        users = [f"u{i}" for i in range(6)]
+        watch.install()
+        watch.reset()
+        with watch.armed():
+            for t in range(12):
+                uid = users[t % len(users)]
+                if uid in s.user_slot:
+                    s.evict(uid)
+                else:
+                    s.admit(uid, evict_lru=True)
+                outs = s.step(drives(t))
+                assert sorted(outs) == sorted(s.user_slot)
+                outs = s.pool_step(drives(t))
+                assert sorted(outs) == sorted(s.user_slot)
+                s.control_step(drives(t))
+        assert watch.violations == 0, watch.violation_signatures
+        assert s.compiled_programs() == progs
+
+    @pytest.mark.parametrize("datapath", ["float32", "int8"])
+    @pytest.mark.parametrize("spiking_readout", [False, True])
+    def test_control_step_is_the_per_uid_window_mean(self, datapath,
+                                                     spiking_readout):
+        """One launch takes every session's window mean (tanh unless the
+        readout spikes) and splits it: equal, within float32 rounding, to
+        reducing each uid's window on its own, on a twin pool."""
+        a, b = (self._sched(datapath, spiking_readout=spiking_readout)
+                for _ in range(2))
+        for s in (a, b):
+            for u in ("x", "y", "z"):
+                s.admit(u)
+            s.evict("y")
+        for t in range(4):
+            d = self._drives(a, t)
+            got = a.control_step(d)
+            windows = b.pool_step(d)
+            assert sorted(got) == sorted(windows) == ["x", "z"]
+            for u, w in windows.items():
+                want = w.mean(axis=0)
+                if not spiking_readout:
+                    want = jnp.tanh(want)
+                assert got[u].shape == (4,)
+                np.testing.assert_allclose(np.asarray(got[u]),
+                                           np.asarray(want),
+                                           rtol=1e-6, atol=1e-7)
+        assert np.abs(np.asarray(windows["x"]).mean(axis=0)).max() > 0.3
+        for x, y in zip(jax.tree.leaves(a.fleet), jax.tree.leaves(b.fleet)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -108,10 +108,21 @@ def test_an_added_cell_traffic_and_metric_need_no_edit(tmp_path):
          "moves": manifest["end_to_end"][0]["name"],
          "workloads": ["ctrl_small"]})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    pins = root / "tests" / "bench" / "pins"
+    shutil.copytree(os.path.join(ROOT, "tests", "bench", "pins"), pins)
+    (pins / "calls.small.json").write_text(json.dumps(
+        {"trace": "traces/fleet.json", "calls": 3, "want": 3.0,
+         "why": "the run's 3 calls"}))
     cell = harness.resolve("ctrl_small", str(root))
     assert cell.traffic["sessions"] == 16
     assert [m["name"] for m in cell.per_layer] == ["calls.small"]
-    assert cell.reader("calls.small").read({"calls": 3}) == 3.0
+    metrics = harness.load_module(
+        os.path.join(ROOT, "tests", "bench", "test_bench_metrics.py"))
+    assert metrics.unpinned(str(root)) == ([], [])
+    run, want = metrics.pinned("calls.small", str(root))
+    assert cell.reader("calls.small").read(run) == want
+    (pins / "calls.small.json").unlink()
+    assert metrics.unpinned(str(root)) == (["calls.small"], [])
 
 
 def test_a_metric_without_workloads_follows_what_it_moves():

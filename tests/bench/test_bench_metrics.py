@@ -1,5 +1,14 @@
 """Each per-layer metric's reader, on a synthetic trace: the number it
-reads, and nothing (None) where the trace holds nothing for it."""
+reads, and nothing (None) where the trace holds nothing for it.
+
+A metric's synthetic run and the reading it pins live in
+``tests/bench/pins/<name>.json``, found by the metric's full name: a trace
+(``devices``: device plane -> ops as ``[name, start_ns, end_ns]``;
+``spans``: host spans in the same form; ``window``; ``least_time_s``;
+``calls``), or ``trace``: a trace file of that directory that the pin's own
+keys override; ``want``, the reading; and ``why``, its arithmetic.  Adding
+a metric adds its reader, its pin and its manifest entry; no edit here."""
+import json
 import os
 import sys
 
@@ -12,58 +21,55 @@ sys.path.insert(0, ROOT)
 from bench import harness  # noqa: E402
 from bench.trace import Event, Trace  # noqa: E402
 
-M = harness.load_manifest(ROOT)
-NAMES = [m["name"] for m in M["per_layer"]]
+NAMES = [m["name"] for m in harness.load_manifest(ROOT)["per_layer"]]
+PIN_KEYS = ("trace", "want", "why")
+
+
+def pin_dir(root=ROOT):
+    return os.path.join(root, "tests", "bench", "pins")
+
+
+def unpinned(root=ROOT):
+    """(per-layer metrics of `root`'s manifest without a pin, pins without
+    a metric)."""
+    names = {m["name"] for m in harness.load_manifest(root)["per_layer"]}
+    pins = {f[:-len(".json")] for f in os.listdir(pin_dir(root))
+            if f.endswith(".json")}
+    return sorted(names - pins), sorted(pins - names)
+
+
+def _events(rows):
+    return sorted((Event(n, float(s), float(e)) for n, s, e in rows),
+                  key=lambda e: e.start)
+
+
+def pinned(name, root=ROOT):
+    """The synthetic run of metric `name` and the reading pinned for it."""
+    with open(os.path.join(pin_dir(root), name + ".json")) as f:
+        pin = json.load(f)
+    run = {}
+    if "trace" in pin:
+        with open(os.path.join(pin_dir(root), pin["trace"])) as f:
+            run = json.load(f)
+    run.update((k, v) for k, v in pin.items() if k not in PIN_KEYS)
+    trace = Trace({plane: _events(ops)
+                   for plane, ops in run["devices"].items()},
+                  _events(run["spans"]))
+    return (dict(run, trace=trace, window=tuple(map(float, run["window"]))),
+            pin["want"])
 
 
 def _reader(name):
     return harness.load_module(harness.reader_path(name, ROOT))
 
 
-def _run(ops, spans, least=1e-9):
-    tr = Trace({"/device:TPU:0": sorted(ops, key=lambda e: e.start)},
-               sorted(spans, key=lambda e: e.start))
-    return {"trace": tr, "window": (0.0, 1000.0), "least_time_s": least,
-            "calls": 2}
-
-
-FLEET = _run(
-    [Event("jit__pool_rollout:_pool_rollout.1 [kernel]", 100, 110),
-     Event("jit__pool_rollout:_pool_rollout.1 [kernel]", 600, 610),
-     Event("jit_squeeze:copy.1", 200, 300)],
-    [Event("bench.window", 0, 1000), Event("bench.call", 50, 450),
-     Event("pool.rollout", 60, 80), Event("bench.call", 500, 900),
-     Event("pool.rollout", 510, 530)])
-LM = _run(
-    [Event("jit__pool_step:while.1", 10, 150),
-     Event("jit__pool_step:fusion.2", 20, 100),
-     Event("jit__pool_step:while.1", 510, 650),
-     Event("jit__pool_step:fusion.2", 520, 600),
-     Event("jit__prefill_session:fusion", 300, 400)],
-    [Event("bench.window", 0, 1000), Event("bench.step", 8, 250),
-     Event("lm.decode_step", 9, 12), Event("bench.step", 508, 900),
-     Event("lm.decode_step", 509, 512)])
-EXPECTED = {
-    "sched_host_ms.fleet": (FLEET, 380e-6),        # 400 - 20 ns per call
-    "sched_host_ms.loop": (FLEET, 380e-6),
-    "device_idle_share.fleet": (FLEET, 88.0),       # 120 of 1000 ns busy
-    "device_idle_share.loop": (FLEET, 88.0),
-    "rollout_roofline.fleet": (FLEET, 10.0),        # 1 ns least / 10 ns
-    "step_mfu.fleet": (FLEET, 0.2),                 # 2 x 1 ns / 1000 ns
-    "sched_host_ms.lm": (LM, 250e-6),    # idle 150 + 350 ns in 2 steps
-    "decode_step_ms.lm": (LM, 140e-6),   # the program busy 280 ns
-    "step_mfu.lm": (LM, 0.2),
-    "device_idle_share.lm": (LM, 62.0),  # 380 of 1000 ns busy
-}
-
-
 def test_every_per_layer_metric_has_a_pinned_reading():
-    assert sorted(EXPECTED) == sorted(NAMES)
+    assert unpinned() == ([], [])
 
 
 @pytest.mark.parametrize("name", NAMES)
 def test_reader_reads_its_number(name):
-    run, want = EXPECTED[name]
+    run, want = pinned(name)
     assert _reader(name).read(run) == pytest.approx(want)
 
 

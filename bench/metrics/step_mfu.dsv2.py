@@ -1,0 +1,3 @@
+"""`step_mfu.lm`'s reading, in the DeepSeek-V2 decode pool's cell."""
+from bench.harness import load_module, reader_path
+read = load_module(reader_path("step_mfu.lm")).read

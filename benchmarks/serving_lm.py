@@ -39,7 +39,6 @@ checked-in one).
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import time
@@ -58,12 +57,6 @@ LAYOUT_ARCH = {"dense": "qwen3-4b", "ssm": "mamba2-1.3b",
 
 def build_model(layout: str, impl: str, datapath: str, neurons: int):
     cfg = factory.build(LAYOUT_ARCH[layout], smoke=True).cfg
-    if cfg.moe is not None:
-        # capacity >= every token any full pool can route: drops become
-        # impossible, so the only cross-row interaction in the decode path
-        # (capacity coupling) is inert and bit-identity is well-defined
-        cfg = cfg.with_(moe=dataclasses.replace(
-            cfg.moe, capacity_factor=float(cfg.moe.num_experts)))
     cfg = cfg.with_(plastic_adapter=True, adapter_neurons=neurons,
                     adapter_impl=impl, adapter_quant=(datapath == "int8"))
     model = factory.build(cfg)

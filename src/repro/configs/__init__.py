@@ -2,7 +2,7 @@
 
 Each module exports CONFIG (the exact published dims) and SMOKE (a reduced
 same-family config for CPU tests).  `get_config(arch)` / `get_smoke(arch)`
-are the public API; `ARCHS` lists every selectable id (10 assigned LM archs
+are the public API; `ARCHS` lists every selectable id (11 LM archs
 + the paper's own SNN controller).
 """
 from __future__ import annotations
@@ -13,7 +13,7 @@ ARCHS = [
     "qwen2-72b", "internlm2-20b", "qwen3-4b", "qwen1.5-32b",
     "zamba2-7b", "deepseek-moe-16b", "grok-1-314b",
     "musicgen-medium", "pixtral-12b", "mamba2-1.3b",
-    "firefly-snn",
+    "deepseek-v2-lite", "firefly-snn",
 ]
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

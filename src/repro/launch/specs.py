@@ -43,6 +43,7 @@ TRAIN_SETUP: dict[str, dict] = {
     "pixtral-12b":      dict(microbatches=2, act_shard="sp"),
     "qwen3-4b":         dict(microbatches=2),
     "deepseek-moe-16b": dict(microbatches=2),
+    "deepseek-v2-lite": dict(microbatches=2),
     "musicgen-medium":  dict(microbatches=2),
     "zamba2-7b":        dict(microbatches=4),
     "mamba2-1.3b":      dict(microbatches=2),

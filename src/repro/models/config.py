@@ -22,6 +22,32 @@ class MoEConfig:
     first_dense: int = 0          # leading dense layers
     first_dense_ff: int = 0       # their FFN width
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum 1
+    # expert parallelism: this chip holds routed experts
+    # [first_held, first_held + num_experts) of a router over
+    # `router_experts` (0: the router's width is num_experts, all held)
+    router_experts: int = 0
+    first_held: int = 0
+
+    @property
+    def n_router(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def is_share(self) -> bool:
+        """True when this layer holds only part of the routed experts."""
+        return self.n_router != self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv 2309.00071), as DeepSeek-V2 applies it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +74,12 @@ class ModelConfig:
     d_ff: int
     vocab: int
     head_dim: Optional[int] = None
+    # multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1_000_000.0
@@ -86,6 +118,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // max(self.n_heads, 1)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def adtype(self):

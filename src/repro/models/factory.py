@@ -50,6 +50,16 @@ def _validate(cfg: ModelConfig) -> None:
         raise ValueError(f"{cfg.name}: layout 'moe' needs cfg.moe")
     if cfg.layout in ("ssm", "hybrid") and cfg.ssm is None:
         raise ValueError(f"{cfg.name}: layout {cfg.layout!r} needs cfg.ssm")
+    if cfg.mla and (cfg.layout not in ("dense", "moe") or cfg.kv_quant):
+        raise ValueError(f"{cfg.name}: latent attention runs in the dense "
+                         "and moe layouts, with its bf16 latent cache")
+    if cfg.moe is not None and not (
+            0 <= cfg.moe.first_held
+            and cfg.moe.first_held + cfg.moe.num_experts
+            <= cfg.moe.n_router):
+        raise ValueError(f"{cfg.name}: held experts [{cfg.moe.first_held}, "
+                         f"{cfg.moe.first_held + cfg.moe.num_experts}) lie "
+                         f"outside the router's {cfg.moe.n_router}")
     if cfg.plastic_adapter:
         if cfg.adapter_impl not in IMPLS:
             raise ValueError(

@@ -7,6 +7,13 @@ outputs scatter back weighted by the router gate.  Under expert parallelism
 the (E, C, D) buffer is sharded E->"model", so the token->expert resharding
 lowers to the all-to-all pattern; compiled FLOPs track ACTIVE experts
 (T * top_k * capacity_factor), not the dense all-experts product.
+
+Serving routes dropless through `apply_held`: the layer is told which
+routed experts it holds (``MoEConfig.first_held`` / ``num_experts`` of a
+router over ``n_router``), routes every token over the whole router, and
+computes the part of the result its own experts give, plus the shared
+experts.  Under expert parallelism each chip holds one such share; on one
+chip the layer runs without the exchange.
 """
 from __future__ import annotations
 
@@ -34,7 +41,8 @@ def plan(cfg: ModelConfig, stack: int = 0) -> dict:
     # sharding.logical_to_physical dedup (deepseek 64e vs grok 8e on 16-way).
     p = {
         "norm": desc((d,), (None,), init="ones"),
-        "router": desc((d, e), (None, None), fan_in=d, dtype="float32"),
+        "router": desc((d, moe.n_router), (None, None), fan_in=d,
+                       dtype="float32"),
         "w_gate": desc((e, d, f), ("model", "data", "model"), fan_in=d),
         "w_up": desc((e, d, f), ("model", "data", "model"), fan_in=d),
         "w_down": desc((e, f, d), ("model", "model", "data"), fan_in=f),
@@ -47,17 +55,9 @@ def plan(cfg: ModelConfig, stack: int = 0) -> dict:
     return p
 
 
-def apply(params, x, cfg: ModelConfig, groups: int = 0, token_mask=None):
-    """x (B,S,D) -> (B,S,D) residual-added MoE FFN.
-
-    ``token_mask`` (B,S) bool marks VALID tokens: masked tokens are routed
-    to the trash row with zero gate weight and — because their expert
-    assignment is rewritten to a sentinel before the dispatch sort — they
-    never consume expert capacity.  This is the continuous-batching pool's
-    no-op contract: a vacant slot's garbage token must not displace an
-    active stream's token from an expert buffer (capacity coupling is the
-    one cross-row interaction in the whole decode path), so active-slot
-    outputs are bit-invariant to neighbour churn.
+def apply(params, x, cfg: ModelConfig, groups: int = 0):
+    """x (B,S,D) -> (B,S,D) residual-added MoE FFN (the training dispatch;
+    tokens past an expert's capacity are dropped).
 
     GROUPED LOCAL DISPATCH (EXPERIMENTS.md §Perf, deepseek/grok cells):
     tokens split into `groups` dispatch groups aligned with the data axis;
@@ -93,13 +93,8 @@ def apply(params, x, cfg: ModelConfig, groups: int = 0, token_mask=None):
                         params["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, expert_idx = jax.lax.top_k(probs, moe.top_k)          # (G,Tg,K)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    if token_mask is not None:
-        # invalid tokens: expert id -> sentinel e, so the stable sort parks
-        # them BEHIND every real assignment — they cannot occupy a capacity
-        # position a valid token would otherwise get
-        mask_g = token_mask.reshape(g_n, tg)
-        expert_idx = jnp.where(mask_g[..., None], expert_idx, e)
+    if moe.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     cap = max(int(moe.capacity_factor * tg * k / e), 1)
 
@@ -110,7 +105,7 @@ def apply(params, x, cfg: ModelConfig, groups: int = 0, token_mask=None):
         sorted_e = flat_e[order]
         pos = jnp.arange(tg * k) - jnp.searchsorted(sorted_e, sorted_e,
                                                     side="left")
-        keep = (pos < cap) & (sorted_e < e)
+        keep = pos < cap
         # dropped slots write to (and read from) a trash row so they never
         # clobber a kept token's buffer slot
         dest = jnp.where(keep, sorted_e * cap + pos, e * cap)
@@ -163,6 +158,42 @@ def apply(params, x, cfg: ModelConfig, groups: int = 0, token_mask=None):
         out = out + swiglu(h, params["ws_gate"], params["ws_up"],
                            params["ws_down"])
     return x + shard_constraint(out, cfg.act_spec)
+
+
+def apply_held(params, x, cfg: ModelConfig, token_mask=None):
+    """Dropless MoE FFN over this layer's held share of the routed experts.
+
+    x (B,S,D) -> (x + the held experts' part + the shared experts,
+    assignments (B,E_held) int32: each row's tokens routed to each held
+    expert).
+    Every token is routed over the whole router (softmax, greedy top-k,
+    renormalised only with ``norm_topk_prob``); each held expert runs
+    densely over all tokens and is weighted by its gate where chosen, 0
+    elsewhere, so no token is ever dropped and rows never interact.
+    ``token_mask`` (B,S) marks valid tokens: the others count nothing.
+    """
+    moe = cfg.moe
+    e, lo = moe.num_experts, moe.first_held
+    h = rms_norm(x, params["norm"], cfg.norm_eps)
+    logits = jnp.einsum("bsd,dr->bsr", h.astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), moe.top_k)
+    if moe.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    chosen = jax.nn.one_hot(idx - lo, e, dtype=jnp.float32)     # (B,S,K,E)
+    if token_mask is not None:
+        chosen = chosen * token_mask[..., None, None].astype(jnp.float32)
+    w = jnp.einsum("bske,bsk->bse", chosen, gates)              # (B,S,E)
+    counts = chosen.sum((1, 2)).astype(jnp.int32)
+    g = jax.nn.silu(jnp.einsum("bsd,edf->bsef", h, params["w_gate"]))
+    u = jnp.einsum("bsd,edf->bsef", h, params["w_up"])
+    eo = jnp.einsum("bsef,efd->bsed", g * u, params["w_down"])
+    out = jnp.einsum("bsed,bse->bsd", eo.astype(jnp.float32),
+                     w).astype(x.dtype)
+    if moe.n_shared:
+        out = out + swiglu(h, params["ws_gate"], params["ws_up"],
+                           params["ws_down"])
+    return x + shard_constraint(out, cfg.act_spec), counts
 
 
 def aux_load_balance_loss(params, x, cfg: ModelConfig):

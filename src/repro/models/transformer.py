@@ -12,6 +12,13 @@ independent program size):
   zsuper    — one SHARED transformer block + (attn_every-1) Mamba2 blocks
               (zamba2; the shared block's params live once at top level)
 
+Attention is GQA (`models.attention`) or, where ``cfg.kv_lora_rank`` is
+set, multi-head latent attention (`models.mla`, deepseek-v2), whose cache
+holds a latent and a rotated key part per position instead of k and v.
+Serving routes MoE layers dropless (`moe.apply_held`), and so does every
+pass of a config that holds only a share of the routed experts; training
+a whole expert set keeps the capacity dispatch (`moe.apply`).
+
 Entry points:
   plan / init / abstract          — parameter plan machinery
   forward                         — full-sequence logits (train/prefill)
@@ -27,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import shard_constraint
-from repro.models import attention, moe as moe_mod, plastic, ssm as ssm_mod
+from repro.models import attention, mla, moe as moe_mod, plastic, \
+    ssm as ssm_mod
 from repro.models.config import ModelConfig
 from repro.models.layers import (ParamDesc, abstract_from_plan,
                                  cross_entropy, init_from_plan, param_count,
@@ -87,14 +95,15 @@ def _mlp_plan(cfg: ModelConfig, d_ff: int, stack: int = 0) -> dict:
 
 
 def _segment_plan(cfg: ModelConfig, kind: str, count: int):
+    attn = mla if cfg.mla else attention
     if kind == "dense":
-        return {"attn": attention.plan(cfg, stack=count),
+        return {"attn": attn.plan(cfg, stack=count),
                 "mlp": _mlp_plan(cfg, cfg.d_ff, stack=count)}
     if kind == "dense_ff":
-        return {"attn": attention.plan(cfg, stack=count),
+        return {"attn": attn.plan(cfg, stack=count),
                 "mlp": _mlp_plan(cfg, cfg.moe.first_dense_ff, stack=count)}
     if kind == "moe":
-        return {"attn": attention.plan(cfg, stack=count),
+        return {"attn": attn.plan(cfg, stack=count),
                 "moe": moe_mod.plan(cfg, stack=count)}
     if kind == "ssm":
         return ssm_mod.plan(cfg, stack=count)
@@ -147,16 +156,26 @@ def _mlp_apply(p, x, cfg: ModelConfig):
 
 def _block_fn(cfg: ModelConfig, kind: str, *, collect_cache: bool,
               attn_impl: str, ssd_impl: str, shared=None):
-    """Returns body(x, p) -> (x, cache_leaf) for one block of `kind`."""
+    """Returns body(x, p) -> (x, cache_leaf) for one block of `kind`.
+    With `collect_cache` (prefill, a serving path) MoE layers route
+    dropless."""
+
+    def attn_apply(p, x):
+        if cfg.mla:
+            return mla.apply(p, x, cfg)
+        return attention.apply(p, x, cfg, impl=attn_impl)
 
     def dense(x, p):
-        x, (k, v) = attention.apply(p["attn"], x, cfg, impl=attn_impl)
+        x, (k, v) = attn_apply(p["attn"], x)
         x = _mlp_apply(p["mlp"], x, cfg)
         return x, ((k, v) if collect_cache else None)
 
     def moe_block(x, p):
-        x, (k, v) = attention.apply(p["attn"], x, cfg, impl=attn_impl)
-        x = moe_mod.apply(p["moe"], x, cfg)
+        x, (k, v) = attn_apply(p["attn"], x)
+        if collect_cache or cfg.moe.is_share:
+            x, _ = moe_mod.apply_held(p["moe"], x, cfg)
+        else:
+            x = moe_mod.apply(p["moe"], x, cfg)
         return x, ((k, v) if collect_cache else None)
 
     def ssm_block(x, p):
@@ -261,6 +280,8 @@ def cache_plan(cfg: ModelConfig, batch: int, max_len: int,
     segs = segments(cfg)
 
     def attn_cache(count):
+        if cfg.mla:
+            return mla.plan_cache(cfg, batch, max_len, count)
         kv = attention.plan_kv_cache(cfg, batch, max_len, count, seq_shard)
         if not cfg.kv_quant:
             return {"k": kv, "v": kv}
@@ -285,9 +306,20 @@ def cache_plan(cfg: ModelConfig, batch: int, max_len: int,
     out = {"segments": seg_caches,
            "index": ParamDesc(idx_shape, idx_spec, init="zeros",
                               dtype="int32")}
+    if cfg.layout == "moe":
+        out["moe_load"] = _plan_moe_load(cfg, batch)
     if cfg.plastic_adapter:
         out["adapter"] = plastic.plan_cache(cfg, batch)
     return out
+
+
+def _plan_moe_load(cfg: ModelConfig, batch: int) -> ParamDesc:
+    """Per stream, the routed assignments of its last decoded token (of
+    a window's tokens, after `decode_rollout`) to each held expert, summed
+    over the MoE layers; 0 after prefill.  `LMScheduler` counts them as
+    the held experts' load."""
+    return ParamDesc((batch, cfg.moe.num_experts), ("data", None),
+                     init="zeros", dtype="int32")
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -314,7 +346,10 @@ def prefill(params, inputs, cfg: ModelConfig, max_len: int, *,
 
     def pack_kv(k, v):
         out = {}
-        if cfg.kv_quant:
+        if cfg.mla:     # k: the normed latent c, v: the rotated k_pe
+            out = {"ckv": _embed_kv(k, bsz, max_len, cfg),
+                   "kpe": _embed_kv(v, bsz, max_len, cfg)}
+        elif cfg.kv_quant:
             kq, ks = attention.quantize_kv(k)
             vq, vs = attention.quantize_kv(v)
             out = {"k": _embed_kv(kq, bsz, max_len, cfg),
@@ -340,6 +375,9 @@ def prefill(params, inputs, cfg: ModelConfig, max_len: int, *,
             sc["ssm"] = {"ssm": state, "conv": conv}
             seg_caches.append(sc)
     cache = {"segments": seg_caches, "index": jnp.asarray(s, jnp.int32)}
+    if cfg.layout == "moe":
+        cache["moe_load"] = init_from_plan(_plan_moe_load(cfg, bsz),
+                                           jax.random.PRNGKey(0))
     if cfg.plastic_adapter:
         cache["adapter"] = init_from_plan(plastic.plan_cache(cfg, bsz),
                                           jax.random.PRNGKey(0))
@@ -357,13 +395,16 @@ def _embed_kv(k, bsz, max_len, cfg):
 def _decode_backbone(params, cache, tokens, cfg: ModelConfig, active=None):
     """Embed + all segments for ONE new token per stream.  tokens (B,1).
 
-    Returns (h (B,1,D) pre-final-norm, new segment caches, new index).
+    Returns (h (B,1,D) pre-final-norm, new segment caches, new index,
+    load): `load` is the (B, E_held) routed assignments of each stream's
+    token to the held experts, summed over the MoE layers (None for a
+    layout without them).
     ``active (B,)`` is the pool's vacant-slot mask, enforcing the TRUE
     no-op contract on every piece of per-stream state: KV/scale cache rows
     are write-gated (`attention._write_at`), SSM/conv states are
     select-gated, per-slot sequence indices freeze, and MoE dispatch
-    sentinels vacant slots' garbage tokens out of expert capacity (the one
-    cross-row interaction in the decode path).  A vacant slot's entire
+    routes dropless (no row can displace another) and counts a vacant
+    slot's token nowhere.  A vacant slot's entire
     session row is bit-identical after any number of pool steps.  Vacant
     rows' hidden-state COMPUTE is garbage, but nothing persistent reads it
     (the adapter and pending-token updates are gated downstream).
@@ -382,11 +423,29 @@ def _decode_backbone(params, cache, tokens, cfg: ModelConfig, active=None):
     h = jnp.take(params["embed"], tokens, axis=0)       # (B,1,D)
     h = shard_constraint(h, ("data", None, None))
 
-    new_segs = []
+    def ffn(p, x, kind):
+        if kind == "moe":
+            return moe_mod.apply_held(p["moe"], x, cfg, token_mask=token_mask)
+        return _mlp_apply(p["mlp"], x, cfg), None
+
+    new_segs, loads = [], []
     for seg_idx, (kind, count) in enumerate(segments(cfg)):
         seg_p = params["segments"][seg_idx]
         c = cache["segments"][seg_idx]
-        if kind in ("dense", "dense_ff", "moe"):
+        if kind in ("dense", "dense_ff", "moe") and cfg.mla:
+            def body(x, xs, _kind=kind):
+                p, c_l, r_l = xs
+                x, cn, rn = mla.decode_step(p["attn"], x, c_l, r_l, index,
+                                            cfg, active=active)
+                x, load = ffn(p, x, _kind)
+                return x, ((cn, rn), load)
+
+            h, ((cs, rs), load) = jax.lax.scan(
+                body, h, (seg_p, c["ckv"], c["kpe"]))
+            new_segs.append({"ckv": cs, "kpe": rs})
+            if load is not None:
+                loads.append(load.sum(0))
+        elif kind in ("dense", "dense_ff", "moe"):
             def body(x, xs, _kind=kind):
                 if cfg.kv_quant:
                     p, k_l, v_l, sk_l, sv_l = xs
@@ -399,23 +458,25 @@ def _decode_backbone(params, cache, tokens, cfg: ModelConfig, active=None):
                                                       index, cfg,
                                                       active=active)
                     skn = svn = None
-                if _kind == "moe":
-                    x = moe_mod.apply(p["moe"], x, cfg,
-                                      token_mask=token_mask)
-                else:
-                    x = _mlp_apply(p["mlp"], x, cfg)
-                if cfg.kv_quant:
-                    return x, (kn, vn, skn, svn)
-                return x, (kn, vn)
+                x, load = ffn(p, x, _kind)
+                out = (kn, vn, skn, svn) if cfg.kv_quant else (kn, vn)
+                return x, (out if load is None else (*out, load))
 
             if cfg.kv_quant:
-                h, (ks, vs, sks, svs) = jax.lax.scan(
+                h, ys = jax.lax.scan(
                     body, h, (seg_p, c["k"], c["v"],
                               c["k_scale"], c["v_scale"]))
+            else:
+                h, ys = jax.lax.scan(body, h, (seg_p, c["k"], c["v"]))
+            if kind == "moe":
+                *ys, load = ys
+                loads.append(load.sum(0))
+            if cfg.kv_quant:
+                ks, vs, sks, svs = ys
                 new_segs.append({"k": ks, "v": vs,
                                  "k_scale": sks, "v_scale": svs})
             else:
-                h, (ks, vs) = jax.lax.scan(body, h, (seg_p, c["k"], c["v"]))
+                ks, vs = ys
                 new_segs.append({"k": ks, "v": vs})
         elif kind == "ssm":
             def body(x, xs):
@@ -472,7 +533,7 @@ def _decode_backbone(params, cache, tokens, cfg: ModelConfig, active=None):
     else:  # per-slot: vacant slots' sequence positions stay frozen
         new_index = index + (active.astype(jnp.int32) if active is not None
                              else 1)
-    return h, new_segs, new_index
+    return h, new_segs, new_index, (sum(loads) if loads else None)
 
 
 def _head(params, h, cfg: ModelConfig):
@@ -495,9 +556,11 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, active=None,
     mesh whose ``"data"`` axis shards a pool's slot rows (see
     `plastic.decode_step`).
     """
-    h, new_segs, new_index = _decode_backbone(params, cache, tokens, cfg,
-                                              active=active)
+    h, new_segs, new_index, load = _decode_backbone(params, cache, tokens,
+                                                    cfg, active=active)
     new_cache = {"segments": new_segs, "index": new_index}
+    if load is not None:
+        new_cache["moe_load"] = load
     if cfg.plastic_adapter:
         h, new_cache["adapter"] = plastic.decode_step(
             params["adapter"], cache["adapter"], h, cfg, active=active,
@@ -529,15 +592,17 @@ def decode_rollout(params, cache, tokens, cfg: ModelConfig, active=None,
 
     def body(carry, tok):
         segs, index = carry
-        h, segs, index = _decode_backbone(
+        h, segs, index, load = _decode_backbone(
             params, {"segments": segs, "index": index}, tok, cfg,
             active=active)
-        return (segs, index), h[:, 0]
+        return (segs, index), (h[:, 0], load)
 
-    (new_segs, new_index), hs = jax.lax.scan(
+    (new_segs, new_index), (hs, loads) = jax.lax.scan(
         body, (cache["segments"], cache["index"]), tk)
     h = jnp.swapaxes(hs, 0, 1)                           # (B,K,D)
     new_cache = {"segments": new_segs, "index": new_index}
+    if loads is not None:       # the window's assignments, all K tokens
+        new_cache["moe_load"] = loads.sum(0)
     if cfg.plastic_adapter:
         h, new_cache["adapter"] = plastic.decode_rollout(
             params["adapter"], cache["adapter"], h, cfg, active=active,

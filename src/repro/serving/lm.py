@@ -19,8 +19,15 @@ K-token window (`decode_window` — the windowed path routes the adapter
 through `plastic.decode_rollout`, so K plasticity steps for every resident
 stream are a single time-fused engine launch).  Occupancy is a runtime
 ``active (B,)`` operand: churn never retraces, vacant slots are bit-exact
-no-ops (the MoE dispatch sentinels their garbage tokens out of expert
-capacity, the adapter freezes its synapses, the cache index holds).
+no-ops (MoE layers route dropless and count their tokens nowhere, the
+adapter freezes its synapses, the cache index holds).  The backbone cache
+is whatever the layout plans: GQA k/v planes, or the latent c and rotated
+k_pe of multi-head latent attention.
+
+For MoE layouts each step also reads back, with its tokens, how many of
+the streams' routed assignments went to the experts this pool holds, into
+the registry counters ``lm_moe_held_assignments`` (per MoE layer) and
+``lm_moe_steps``.
 
 `benchmarks/serving_lm.py` pins the contracts: zero recompiles under
 churn, and evict -> persist -> re-admit bit-identity mid-generation, per
@@ -62,11 +69,11 @@ class LMScheduler(SessionPool):
       mesh:    optional device mesh (see `SessionPool`): the decode pool —
                KV/SSM planes, adapter rows, sequence indices — shards over
                its slot axes and the decode launches run as sharding-
-               constrained jit (GSPMD), NOT shard_map: the MoE capacity/
-               cumsum stages reduce ACROSS slots, and GSPMD partitions them
-               without changing their semantics, where a manual per-shard
-               lowering would.  Only the plastic adapter's engine launch
-               runs per shard (`engine.fleet_spmd`): its slot rows are
+               constrained jit (GSPMD), NOT shard_map: GSPMD partitions
+               the backbone without changing its semantics, where a manual
+               per-shard lowering would restate them.  Only the plastic
+               adapter's engine launch runs per shard
+               (`engine.fleet_spmd`): its slot rows are
                independent, and GSPMD cannot partition a Pallas kernel.
                The partitioned program is not the unmeshed one, so bf16
                logits may differ by roundings and a greedy token flips
@@ -192,6 +199,17 @@ class LMScheduler(SessionPool):
         # variants register up-front (untraced => 0 executables) so a
         # telemetry-off run audits them without compiling anything.
         self._prefill = jax.jit(_prefill_session)
+        # MoE layouts: the held experts' load, read back with each step's
+        # tokens (cache["moe_load"], summed over the MoE layers)
+        self._moe_layers = (self.cfg.n_layers - self.cfg.moe.first_dense
+                            if self.cfg.layout == "moe" else 0)
+        if self._moe_layers:
+            self._m_moe_held = self.metrics.counter(
+                "lm_moe_held_assignments",
+                "decode steps' routed assignments to the held experts, "
+                "per MoE layer")
+            self._m_moe_steps = self.metrics.counter(
+                "lm_moe_steps", "decode steps of a pool with MoE layers")
         self._step_fn = jax.jit(_pool_step)
         self._window_fn = jax.jit(_pool_window)
         self._step_tel_fn = jax.jit(_pool_step_tel)
@@ -286,6 +304,10 @@ class LMScheduler(SessionPool):
                 self.pool, nxt = self._step_fn(self.params, self.pool,
                                                self._active_mask())
         self.advance_steps(1)
+        if self._moe_layers:
+            nxt, load = jax.device_get((nxt, self.pool["cache"]["moe_load"]))
+            self._m_moe_held.inc(float(load.sum()) / self._moe_layers)
+            self._m_moe_steps.inc()
         nxt = np.asarray(nxt)
         toks = {uid: int(nxt[slot]) for uid, slot in self.user_slot.items()}
         if not telemetry:

@@ -23,7 +23,6 @@ Pins, mirroring tests/test_serving.py for the LM path:
      prefill), telemetry variants one each, and ONLY the entry point whose
      shape legitimately changed may grow.
 """
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -45,12 +44,6 @@ LAYOUT_ARCH = {"dense": "qwen3-4b", "ssm": "mamba2-1.3b",
 
 def _model(layout, impl, datapath, neurons=8):
     cfg = factory.build(LAYOUT_ARCH[layout], smoke=True).cfg
-    if cfg.moe is not None:
-        # capacity >= every routable token: cross-row capacity coupling
-        # inert, so per-stream bit-identity is well-defined (DESIGN.md
-        # §Arch-applicability)
-        cfg = cfg.with_(moe=dataclasses.replace(
-            cfg.moe, capacity_factor=float(cfg.moe.num_experts)))
     cfg = cfg.with_(plastic_adapter=True, adapter_neurons=neurons,
                     adapter_impl=impl, adapter_quant=(datapath == "int8"))
     model = factory.build(cfg)

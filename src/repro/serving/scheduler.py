@@ -316,7 +316,7 @@ class SessionPool:
                              f"got {device}")
         return range(device * per, (device + 1) * per)
 
-    def _active_mask(self) -> jax.Array:
+    def _active_rows(self) -> np.ndarray:
         # lost AND quarantined slots are masked out like vacant ones: a
         # stranded session is frozen until drain_failed re-homes it, an
         # unhealthy one until rollback restores it — the mask is a runtime
@@ -325,7 +325,11 @@ class SessionPool:
         for s, u in enumerate(self.slot_user):
             mask[s] = (u is not None and s not in self._lost_slots
                        and s not in self._quarantined)
-        return jnp.asarray(mask)
+        return mask
+
+    def _active_mask(self) -> jax.Array:
+        """The active mask `_active_rows` on the device."""
+        return jnp.asarray(self._active_rows())
 
     def compiled_programs(self) -> Dict[str, int]:
         """Per-entry-point executable counts: {name: compiled programs}.
@@ -1030,8 +1034,9 @@ class FleetScheduler(SessionPool):
 
     def _gather_rows(self, drives: Mapping[str, jax.Array],
                      teach: Optional[Mapping[str, jax.Array]]
-                     ) -> tuple[jax.Array, Optional[jax.Array]]:
-        """Validate uid coverage and pack per-session rows into slot order."""
+                     ) -> tuple[np.ndarray, Optional[np.ndarray]]:
+        """Validate uid coverage and pack per-session rows into slot order,
+        on the host."""
         missing = [u for u in self.user_slot if u not in drives]
         extra = [u for u in drives if u not in self.user_slot]
         if missing or extra:
@@ -1052,8 +1057,45 @@ class FleetScheduler(SessionPool):
             tarr = np.zeros((self.slots, m_out), np.float32)
             for uid, row in teach.items():
                 tarr[self.user_slot[uid]] = np.asarray(row, np.float32)
-            tarr = jnp.asarray(tarr)
-        return jnp.asarray(drive), tarr
+        return drive, tarr
+
+    def _dispatch(self, drives: Mapping[str, jax.Array],
+                  teach: Optional[Mapping[str, jax.Array]],
+                  k: Optional[int], telemetry: bool, record: bool) -> tuple:
+        """Pack one call on the host (the span ``pool.pack``) and launch its
+        pool program (``pool.step``, or ``pool.rollout`` for a K-step
+        window when `k` is given); returns the program's outputs, the new
+        pool state already installed.
+
+        Every per-call input (drives or the held (K, B, n_in) window, the
+        active mask, teach, the per-session step counters) reaches the
+        program as a numpy array, so the program's own dispatch carries
+        them to the device: a call makes no transfer or eager device op
+        of its own."""
+        with phase("pool.pack"):
+            x, tarr = self._gather_rows(drives, teach)
+            if k is not None:
+                x = np.broadcast_to(x[None], (k,) + x.shape)
+        span = "pool.step" if k is None else "pool.rollout"
+        if record:
+            rec = self._ensure_recorder()
+            fn = self._step_rec if k is None else self._rollout_rec
+            with phase(span):
+                res = fn(self.fleet, x, self._active_rows(), tarr,
+                         self._steps.astype(np.int32),
+                         rec, np.int32(self._rec_pos))
+            self._rec, self.last_verdict = res[3], res[4]
+            self._rec_pos += 1
+        else:
+            if k is None:
+                fn = self._step_tel if telemetry else self._step
+            else:
+                fn = self._rollout_tel if telemetry else self._rollout
+            with phase(span):
+                res = fn(self.fleet, x, self._active_rows(), tarr,
+                         self._steps.astype(np.int32))
+        self.fleet = res[0]
+        return res
 
     def _unpack(self, outs: jax.Array, k: int, slot_axis: int,
                 actions: bool = False) -> Dict[str, jax.Array]:
@@ -1089,25 +1131,8 @@ class FleetScheduler(SessionPool):
         ``telemetry=True`` too to ALSO get the host-side tuple return and
         summary gauges (same single program either way).
         """
-        with phase("pool.pack"):
-            drive, tarr = self._gather_rows(drives, teach)
-        if record:
-            rec = self._ensure_recorder()
-            with phase("pool.step"):
-                res = self._step_rec(
-                    self.fleet, drive, self._active_mask(), tarr,
-                    jnp.asarray(self._steps.astype(np.int32)),
-                    rec, jnp.int32(self._rec_pos))
-            self.fleet, out = res[0], res[1]
-            self._rec, self.last_verdict = res[3], res[4]
-            self._rec_pos += 1
-        else:
-            fn = self._step_tel if telemetry else self._step
-            with phase("pool.step"):
-                res = fn(self.fleet, drive, self._active_mask(), tarr,
-                         jnp.asarray(self._steps.astype(np.int32)))
-            self.fleet, out = res[0], res[1]
-        outputs = self._unpack(out, 1, slot_axis=0)
+        res = self._dispatch(drives, teach, None, telemetry, record)
+        outputs = self._unpack(res[1], 1, slot_axis=0)
         if not telemetry:
             return outputs
         tel: FleetTelemetry = res[2]
@@ -1160,26 +1185,7 @@ class FleetScheduler(SessionPool):
         k = self.cfg.timesteps if timesteps is None else int(timesteps)
         if k < 1:
             raise ValueError(f"pool_step needs timesteps >= 1, got {k}")
-        n_in = self.cfg.layer_sizes[0]
-        with phase("pool.pack"):
-            drive, tarr = self._gather_rows(drives, teach)
-            window = jnp.broadcast_to(drive[None], (k, self.slots, n_in))
-        if record:
-            rec = self._ensure_recorder()
-            with phase("pool.rollout"):
-                res = self._rollout_rec(
-                    self.fleet, window, self._active_mask(), tarr,
-                    jnp.asarray(self._steps.astype(np.int32)),
-                    rec, jnp.int32(self._rec_pos))
-            self._rec, self.last_verdict = res[3], res[4]
-            self._rec_pos += 1
-        else:
-            fn = self._rollout_tel if telemetry else self._rollout
-            with phase("pool.rollout"):
-                res = fn(self.fleet, window, self._active_mask(), tarr,
-                         jnp.asarray(self._steps.astype(np.int32)))
-        self.fleet = res[0]
-        return res, k
+        return self._dispatch(drives, teach, k, telemetry, record), k
 
     def control_step(self, obs: Mapping[str, jax.Array]
                      ) -> Dict[str, jax.Array]:

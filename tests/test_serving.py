@@ -537,3 +537,167 @@ class TestOneLaunchUnpack:
         assert np.abs(np.asarray(windows["x"]).mean(axis=0)).max() > 0.3
         for x, y in zip(jax.tree.leaves(a.fleet), jax.tree.leaves(b.fleet)):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+class _Refused:
+    """A module as `repro.serving.scheduler` sees it, with the names in
+    `refused` (all of them where it is None) raising when used."""
+
+    def __init__(self, module, refused=None):
+        self._module, self._refused = module, refused
+
+    def __getattr__(self, name):
+        if self._refused is None or name in self._refused:
+            def refuse(*args, **kwargs):
+                raise AssertionError(
+                    f"{self._module.__name__}.{name} inside a warm pool "
+                    "call: a device op outside the pool program")
+            return refuse
+        return getattr(self._module, name)
+
+
+def _pool_for_dispatch(datapath="float32", meshed=False, slots=4,
+                       health=None):
+    from repro.distributed import sharding as dsh
+    cfg = snn.SNNConfig(layer_sizes=(6, 12, 4), timesteps=3)
+    if datapath == "int8":
+        cfg = snn.quant_config(cfg)
+    theta = snn.init_theta(cfg, jax.random.PRNGKey(0), scale=0.2)
+    # the CPU test devices: a mesh over four where the lane forces them
+    mesh = (dsh.fleet_mesh(4 if len(jax.devices()) >= 4 else 1)
+            if meshed else None)
+    return FleetScheduler(cfg, theta, slots=slots, store=SessionStore(),
+                          mesh=mesh, health=health)
+
+
+def _dispatch_drives(sched, t, n=6):
+    return {u: 2.0 * np.sin(0.7 * t + 1.3 * ord(u[-1])
+                            + np.arange(n)).astype(np.float32)
+            for u in sched.active_users}
+
+
+class TestOneDispatch:
+    """A fleet call's per-call inputs (the drives or the held window, the
+    active mask, teach, the per-session step counters) reach the device
+    only as numpy arguments of the pool program: once warm, a call makes
+    two launches, the pool program and ``pool_unpack``, and no transfer
+    or eager op of its own."""
+
+    @pytest.mark.parametrize("meshed", [False, True])
+    @pytest.mark.parametrize("method", ["step", "pool_step", "control_step"])
+    def test_warm_call_makes_two_launches_and_no_transfer(
+            self, method, meshed, monkeypatch):
+        from repro.serving import scheduler as sched_mod
+        s = _pool_for_dispatch(meshed=meshed)
+        for u in ("a", "b", "c"):
+            s.admit(u)
+        teach = ({u: np.ones(4, np.float32) for u in ("a", "c")}
+                 if method != "control_step" else None)
+
+        def call(t):
+            kw = {} if teach is None else {"teach": teach}
+            return getattr(s, method)(_dispatch_drives(s, t), **kw)
+
+        call(0)                                            # warm-up
+        progs = s.compiled_programs()
+        prog = "_step" if method == "step" else "_rollout"
+        launches = []
+
+        def spy(fleet, x, active, tarr, seeds, _real=getattr(s, prog)):
+            launches.append(prog)
+            k = () if method == "step" else (3,)
+            for name, a, shape in (("x", x, k + (4, 6)),
+                                   ("active", active, (4,)),
+                                   ("seeds", seeds, (4,))):
+                assert type(a) is np.ndarray, (name, type(a))
+                assert a.shape == shape, (name, a.shape)
+            assert active.dtype == np.bool_ and seeds.dtype == np.int32
+            assert active.tolist() == [True, True, True, False]
+            if teach is None:
+                assert tarr is None
+            else:
+                assert type(tarr) is np.ndarray and tarr.shape == (4, 4)
+            return _real(fleet, x, active, tarr, seeds)
+
+        def split(*args, _real=s._split, **kwargs):
+            launches.append("pool_unpack")
+            return _real(*args, **kwargs)
+
+        monkeypatch.setattr(s, prog, spy)
+        monkeypatch.setattr(s, "_split", split)
+        monkeypatch.setattr(sched_mod, "jnp", _Refused(jnp))
+        monkeypatch.setattr(sched_mod, "jax", _Refused(
+            jax, ("device_put", "device_get", "block_until_ready")))
+        got = call(1)
+        monkeypatch.undo()
+        assert launches == [prog, "pool_unpack"]
+        assert sorted(got) == ["a", "b", "c"]
+        assert s.compiled_programs() == progs
+
+
+def _reference_call(sched, drives, teach, k):
+    """One call of `sched` dispatched as the pool once did: every input
+    made a device array by its own transfer or eager op before the pool
+    program ran.  Returns uid -> output."""
+    n_in = sched.cfg.layer_sizes[0]
+    drive = np.zeros((sched.slots, n_in), np.float32)
+    for uid, row in drives.items():
+        drive[sched.user_slot[uid]] = row
+    tarr = None
+    if teach is not None:
+        tarr = np.zeros((sched.slots, sched.cfg.layer_sizes[-1]),
+                        np.float32)
+        for uid, row in teach.items():
+            tarr[sched.user_slot[uid]] = row
+        tarr = jnp.asarray(tarr)
+    x = jnp.asarray(drive)
+    if k is not None:
+        x = jnp.broadcast_to(x[None], (k, sched.slots, n_in))
+    fn = sched._step if k is None else sched._rollout
+    res = fn(sched.fleet, x, sched._active_mask(), tarr,
+             jnp.asarray(sched._steps.astype(np.int32)))
+    sched.fleet = res[0]
+    sched.advance_steps(1 if k is None else k)
+    axis = 0 if k is None else 1
+    return {u: np.take(np.asarray(res[1]), slot, axis=axis)
+            for u, slot in sched.user_slot.items()}
+
+
+class TestHostOperandsBitwise:
+    """Handing the pool program host operands changes no number: pool
+    state and every session's outputs are bitwise those of a reference
+    run that made each operand a device array first."""
+
+    @pytest.mark.parametrize("meshed", [False, True])
+    @pytest.mark.parametrize("method", ["step", "pool_step"])
+    @pytest.mark.parametrize("with_teach", [False, True])
+    @pytest.mark.parametrize("datapath", ["float32", "int8"])
+    def test_matches_device_operand_reference(self, datapath, with_teach,
+                                              method, meshed):
+        from repro.obs.health import HealthConfig
+        prog, ref = (_pool_for_dispatch(datapath, meshed, slots=4,
+                                        health=HealthConfig())
+                     for _ in range(2))
+        for s in (prog, ref):
+            for u in ("a", "b", "c", "d"):
+                s.admit(u)
+        k = None if method == "step" else 3
+        for t in range(6):
+            if t == 2:                    # one vacant, one quarantined slot
+                for s in (prog, ref):
+                    s.evict("b")
+                    s.quarantine("c")
+            d = _dispatch_drives(prog, t)
+            teach = ({u: np.full(4, 0.5 * (t % 3), np.float32)
+                      for u in ("a", "d")} if with_teach else None)
+            kw = {} if k is None else {"timesteps": k}
+            got = getattr(prog, method)(d, teach=teach, **kw)
+            want = _reference_call(ref, d, teach, k)
+            assert sorted(got) == sorted(want) == sorted(prog.user_slot)
+            for u in want:
+                np.testing.assert_array_equal(np.asarray(got[u]), want[u])
+            for x, y in zip(jax.tree.leaves(prog.fleet),
+                            jax.tree.leaves(ref.fleet)):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        assert np.abs(want["a"]).max() > 0.1
+        np.testing.assert_array_equal(prog._steps, ref._steps)
